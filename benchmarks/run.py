@@ -106,6 +106,8 @@ def main() -> None:
                     help="comma-separated subset: " + "|".join(SUITE_NAMES))
     args = ap.parse_args()
     quick = not args.full
+    from repro import compile_cache
+    compile_cache.enable()
     os.makedirs("experiments", exist_ok=True)
 
     from benchmarks import (fig1_depth_staleness, fig2_algorithms,

@@ -129,6 +129,17 @@ def init_state(params: Pytree, optimizer: Optimizer, cfg: StaleSyncConfig,
     )
 
 
+def _ring_rows(ring: jax.Array, read: jax.Array) -> jax.Array:
+    """Worker p's row from slot ``read[p]`` of a [slots, P, ...] ring ->
+    [P, ...]. A dynamic index batched over the worker axis, not
+    ``take_along_axis``: the TPU compiler splits a plain gather whose slice
+    spans a packed row into pieces in proportion to its width, which took
+    longer than 15 minutes to compile at deepseek-7b widths."""
+    return jax.vmap(
+        lambda col, r: jax.lax.dynamic_index_in_dim(col, r, 0, keepdims=False),
+        in_axes=(1, 0))(ring, read)
+
+
 def make_stale_train_step(
     loss_fn: Callable[[Pytree, Pytree], jax.Array],
     optimizer: Optimizer,
@@ -204,6 +215,14 @@ def make_stale_train_step(
             d = jnp.minimum(d, jnp.asarray(bound, jnp.int32))
         return jnp.minimum(d, step)
 
+    def pack_grads(tree, dtype=jnp.float32):
+        """Gradients as packed [P, D] (or [D]) rows. Rows bound for the ring
+        are packed in the ring's dtype: a cast between the packing
+        concatenate and the ring write made the TPU compiler take minutes
+        at deepseek-7b widths."""
+        return tm.tree_pack(tree, lead_ndim=1 if cfg.per_worker_delays else 0,
+                            dtype=dtype, pad_to=dispatch.PACK_ALIGN)
+
     def fused_tail(state, losses, gtree, kdelay, key, bound, comp):
         """Megakernel tail: everything after the backward pass is ONE
         ``dispatch.fused_update`` pass over the packed [D] view — EF split
@@ -214,8 +233,6 @@ def make_stale_train_step(
         slots = cfg.slots
         write = jnp.mod(state.step, slots)
         spec = tm.pack_spec(state.params)
-        gvec = tm.tree_pack(gtree, lead_ndim=1 if per else 0,
-                            pad_to=dispatch.PACK_ALIGN)
         if cfg.s == 0:
             d = jnp.zeros((p,) if per else (), jnp.int32)
         else:
@@ -242,10 +259,10 @@ def make_stale_train_step(
             # Gather the PRE-write ring rows; the kernel substitutes this
             # step's sent for fresh (delay 0) rows, so the sparse payload
             # only has to reach the ring after the kernel.
-            acc, thr, mom_in = compensator.ef_inputs(comp, gvec, spec.total)
+            acc, thr, mom_in = compensator.ef_inputs(comp, pack_grads(gtree),
+                                                     spec.total)
             if per:
-                sel = jnp.take_along_axis(
-                    state.gbuf, read.reshape((1, p, 1)), axis=0)[0]
+                sel = _ring_rows(state.gbuf, read)
                 weights = jnp.full((p,), 1.0 / p, jnp.float32)
             else:
                 sel = jax.lax.dynamic_index_in_dim(state.gbuf, read, 0,
@@ -271,10 +288,9 @@ def make_stale_train_step(
             # written ring (fresh rows come back verbatim) — the same
             # write-then-read order as the three-dispatch path.
             gbuf = jax.lax.dynamic_update_index_in_dim(
-                state.gbuf, gvec.astype(state.gbuf.dtype), write, 0)
+                state.gbuf, pack_grads(gtree, state.gbuf.dtype), write, 0)
             if per:
-                sel = jnp.take_along_axis(
-                    gbuf, read.reshape((1, p, 1)), axis=0)[0]
+                sel = _ring_rows(gbuf, read)
                 weights = jnp.full((p,), 1.0 / p, jnp.float32)
             else:
                 sel = jax.lax.dynamic_index_in_dim(gbuf, read, 0,
@@ -340,16 +356,17 @@ def make_stale_train_step(
             # is ONE fused weighted reduction (dispatch.stale_accum) over the
             # selected rows instead of per-leaf gather + mean.
             spec = tm.pack_spec(state.params)
-            pad = dispatch.PACK_ALIGN
-            gvec = (tm.tree_pack(grads, lead_ndim=1, pad_to=pad)
-                    if cfg.per_worker_delays
-                    else tm.tree_pack(gmean, pad_to=pad))
+            sent_tree = grads if cfg.per_worker_delays else gmean
+            gvec = pack_grads(sent_tree)
             if compensator is not None and compensator.sparsifies:
                 gvec, comp_box[0], cm = compensator.sparsify_packed(
                     comp_box[0], gvec, spec.total)
                 cmetrics.update(cm)
-            gbuf = jax.lax.dynamic_update_index_in_dim(
-                state.gbuf, gvec.astype(state.gbuf.dtype), write, 0)
+                row = gvec.astype(state.gbuf.dtype)
+            else:
+                row = pack_grads(sent_tree, state.gbuf.dtype)
+            gbuf = jax.lax.dynamic_update_index_in_dim(state.gbuf, row,
+                                                       write, 0)
 
             def kernel_agg(sel, weights):
                 aggv = dispatch.stale_accum(
@@ -391,18 +408,12 @@ def make_stale_train_step(
 
             if cfg.kernels:
                 # [P, D]: each worker's delayed packed row, fused-averaged.
-                sel = jnp.take_along_axis(
-                    gbuf, read.reshape((1, p, 1)), axis=0)[0]
+                sel = _ring_rows(gbuf, read)
                 agg = kernel_agg(sel, jnp.full((p,), 1.0 / p, jnp.float32))
             else:
-                def select(buf):
-                    # buf [slots, P, ...]; per-worker delayed slot.
-                    sel = jnp.take_along_axis(
-                        buf, read.reshape((1, p) + (1,) * (buf.ndim - 2)),
-                        axis=0)
-                    return sel[0].astype(jnp.float32).mean(axis=0)
-
-                agg = jax.tree.map(select, gbuf)
+                agg = jax.tree.map(
+                    lambda buf: _ring_rows(buf, read).astype(
+                        jnp.float32).mean(axis=0), gbuf)
             staleness = d
         else:
             # Theorem-1 form: one delayed AGGREGATE gradient per step.

@@ -107,6 +107,19 @@ def _packed_width(params: Pytree) -> int:
     return tm.padded_size(tm.pack_spec(params).total, dispatch.PACK_ALIGN)
 
 
+def _worker_keys(key: jax.Array, caches: Pytree) -> jax.Array:
+    """One update key per worker, placed like the caches' worker axis. Under
+    a mesh with Explicit axes the worker axis's sharding is part of each
+    array's type, and ``vmap`` refuses mapped inputs sharded differently."""
+    leaf = jax.tree.leaves(caches)[0]
+    keys = jax.random.split(key, leaf.shape[0])
+    sharding = jax.typeof(leaf).sharding
+    if len(sharding.spec) and sharding.spec[0] is not None:
+        keys = jax.sharding.reshard(keys, jax.sharding.NamedSharding(
+            sharding.mesh, jax.sharding.PartitionSpec(sharding.spec[0])))
+    return keys
+
+
 def _is_packed(state: SimState) -> bool:
     """Packed states carry ONE pending array whose tree shape differs from
     the caches tree (a single [P, B, D] leaf)."""
@@ -262,7 +275,7 @@ def make_sim_step(
         caches = tm.tree_unpack(flat.reshape(p, -1), pspec)
 
         # 2. compute (identical to the tree path).
-        worker_keys = jax.random.split(kupd, p)
+        worker_keys = _worker_keys(kupd, caches)
         updates, update_state, metrics = jax.vmap(update_fn)(
             caches, state.update_state, batches, worker_keys)
 
@@ -323,7 +336,7 @@ def make_sim_step(
         #    fused pass over the flattened packed view. The moments stay
         #    packed in update_state ([P, D] fp32), read/written exactly
         #    once; the delta rows ARE the packed transport payload.
-        worker_keys = jax.random.split(kupd, p)
+        worker_keys = _worker_keys(kupd, caches)
 
         def grad_one(cache, batch, wkey):
             if fused["takes_key"]:
@@ -397,7 +410,7 @@ def make_sim_step(
             server_state = state.server_state
 
         # 2. every worker computes its update from its own (stale) cache.
-        worker_keys = jax.random.split(kupd, cfg.num_workers)
+        worker_keys = _worker_keys(kupd, caches)
         updates, update_state, metrics = jax.vmap(update_fn)(
             caches, state.update_state, batches, worker_keys
         )

@@ -228,8 +228,13 @@ class Engine:
             params = self._init_params(key)
         inner = self._init_inner(params, update_state, key)
         comp = self._init_comp(params) if self._init_comp is not None else ()
-        return EngineState(inner=inner, bound=jnp.int32(self._max_bound),
-                           comp=comp)
+        state = EngineState(inner=inner, bound=jnp.int32(self._max_bound),
+                            comp=comp)
+        if self._plan is not None:
+            # Placed as the step's outputs are: an unplaced state is another
+            # input type to jit, and the second step would compile again.
+            state = jax.device_put(state, self._plan.in_shardings[0])
+        return state
 
     def step(self, state: EngineState, batch) -> Tuple[EngineState, dict]:
         """One engine step (jit-compiled): ``(state, batch) -> (state, metrics)``."""
@@ -314,15 +319,23 @@ def kernel_placement_ok(kernels: str, arch=None, mesh=None) -> Tuple[bool, str]:
     optimizer): FSDP archs shard param dims over 'data' and a mesh with a
     model axis > 1 shards them over 'model' — a packed view mixes leaves,
     so either placement would be silently replaced by per-step all-gathers.
+    XLA cannot partition a compiled Mosaic kernel, so on a TPU no packed
+    kernel runs on a mesh of several devices (the interpreter lowers to
+    plain, partitionable HLO).
     Returns ``(ok, why_not)``; ``kernels="on"`` overrides the model-axis
-    veto (an explicit, profiled choice) but never the FSDP one.
+    veto (an explicit, profiled choice) but never the FSDP or Mosaic ones.
     """
     if kernels == "off":
         return False, "config off"
+    from repro.kernels import dispatch
     from repro.sharding import rules as rules_lib
     arch_id = getattr(arch, "arch_id", arch)
     if arch_id in rules_lib.FSDP_ARCHS:
         return False, "FSDP placement"
+    if (mesh is not None and mesh.devices.size > 1
+            and not dispatch.interpret_mode()):
+        return False, (f"compiled Pallas kernels cannot be partitioned over "
+                       f"{mesh.devices.size} devices")
     if kernels == "auto" and mesh is not None:
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         if sizes.get("model", 1) > 1:
@@ -400,9 +413,9 @@ def build_engine(api_or_loss, optimizer: Optional[optlib.Optimizer],
             if not kernel_delivery and cfg.kernels == "on":
                 arch_id = getattr(arch, "arch_id", arch)
                 raise ValueError(
-                    f"kernels='on' is unsupported for FSDP arch {arch_id!r}: "
-                    "the packed ring buffer cannot keep the 'embed'->data "
-                    "placement; use kernels='auto' (falls back to tree math)")
+                    f"kernels='on' is unsupported for arch {arch_id!r} on "
+                    f"this placement ({why}): the packed ring buffer cannot "
+                    "run there; use kernels='auto' (falls back to tree math)")
     if mode in ("stale-psum", "ssp", "simulate"):
         delivery = "packed" if kernel_delivery else "tree"
     else:

@@ -40,7 +40,7 @@ def _kernel(hist_ref, g_ref, dots_ref, hsq_ref, gsq_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def coherence_dots(history: jax.Array, g: jax.Array, block_d: int = 2048,
-                   interpret: bool = True):
+                   *, interpret: bool):
     """history [W, D], g [D] -> (dots [W], hist_sq [W], g_sq scalar)."""
     w, d = history.shape
     assert g.shape == (d,)

@@ -69,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     static_argnames=("causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    *, interpret: bool):
     """q [B,Sq,H,hd], k/v [B,Sk,Hkv,hd] -> [B,Sq,H,hd].
 
     GQA: q head h reads kv head h // (H//Hkv). Sq/Sk need not be multiples of

@@ -32,7 +32,7 @@ def _kernel(p_ref, m_ref, v_ref, g_ref, sc_ref, p_out, m_out, v_out):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def fused_adam(p, m, v, g, lr, b1, b2, eps, step, block_d: int = 2048,
-               interpret: bool = True):
+               *, interpret: bool):
     """All of p, m, v, g are [D]; returns (p', m', v'). step >= 1."""
     (d,) = p.shape
     assert d % block_d == 0, f"D={d} must be a multiple of block_d={block_d}"

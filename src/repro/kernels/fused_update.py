@@ -106,7 +106,7 @@ def _kernel_ef_mom(p_ref, m_ref, v_ref, stale_ref, w_ref, acc_ref, thr_ref,
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def fused_update(p, m, v, stale, weights, scalars, acc=None, thr=None,
                  fresh=None, mom=None, block_d: int = 2048,
-                 interpret: bool = True):
+                 *, interpret: bool):
     """p/m/v [D]; stale [R, D]; weights [R]; scalars [7] stacked
     ``[lr, b1, b2, eps, bc1, bc2, scale]``. Optional EF rows acc [R, D] /
     thr [R] / fresh [R] (and mom [R, D]) switch in the split variants.

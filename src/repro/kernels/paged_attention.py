@@ -81,8 +81,8 @@ def _kernel(tables_ref, pos_ref, meta_ref, q_ref, kn_ref, vn_ref,
     def _new_token():
         # Fold in the just-projected token (always valid: it attends to
         # itself under both full-causal and sliding-window), then finish.
-        kn = kn_ref[0].astype(jnp.float32).reshape(hkv, hd)
-        vn = vn_ref[0].astype(jnp.float32).reshape(hkv, hd)
+        kn = kn_ref[0, 0].astype(jnp.float32).reshape(hkv, hd)
+        vn = vn_ref[0, 0].astype(jnp.float32).reshape(hkv, hd)
         for n in range(hkv):
             sl = slice(n * g, (n + 1) * g)
             sc = (q[sl] @ kn[n][:, None])[:, 0]          # [g]
@@ -105,7 +105,7 @@ def _kernel(tables_ref, pos_ref, meta_ref, q_ref, kn_ref, vn_ref,
 def paged_attention(q, k_new, v_new, pages, tables, pos, layer, *,
                     k_off: int, v_off: int, kv_heads: int, head_dim: int,
                     tokens: int, page_tokens: int, window: int = 0,
-                    interpret: bool = True):
+                    interpret: bool):
     """q [S,H,hd]; k_new/v_new [S,Hkv,hd]; pages [P+1,T,W] (packed pool);
     tables [S,PPS] page ids (null = P); pos [S] absolute decode positions;
     ``layer`` a traced scalar selecting the per-layer K/V column block at
@@ -128,8 +128,11 @@ def paged_attention(q, k_new, v_new, pages, tables, pos, layer, *,
     meta = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     tables = tables.astype(jnp.int32)
     posv = pos.astype(jnp.int32)
-    knf = k_new.reshape(s, kvsz)
-    vnf = v_new.reshape(s, kvsz)
+    # [S, 1, kvsz] with a (1, 1, kvsz) block: the last two block dims then
+    # span the array's own (1, kvsz), which Mosaic's (8, 128) tiling rule
+    # accepts for any slot count (a (1, kvsz) block over [S, kvsz] does not).
+    knf = k_new.reshape(s, 1, kvsz)
+    vnf = v_new.reshape(s, 1, kvsz)
 
     def page_map(col0):
         def index_map(si, j, tables_ref, pos_ref, meta_ref):
@@ -147,8 +150,8 @@ def paged_attention(q, k_new, v_new, pages, tables, pos, layer, *,
         grid=(s, pps + 1),
         in_specs=[
             pl.BlockSpec((1, h, hd), lambda si, j, *_: (si, 0, 0)),
-            pl.BlockSpec((1, kvsz), lambda si, j, *_: (si, 0)),
-            pl.BlockSpec((1, kvsz), lambda si, j, *_: (si, 0)),
+            pl.BlockSpec((1, 1, kvsz), lambda si, j, *_: (si, 0, 0)),
+            pl.BlockSpec((1, 1, kvsz), lambda si, j, *_: (si, 0, 0)),
             pl.BlockSpec((1, page_tokens, kvsz), page_map(kcol)),
             pl.BlockSpec((1, page_tokens, kvsz), page_map(vcol)),
         ],

@@ -8,9 +8,12 @@ single fused pass producing both outputs, instead of three elementwise ops
 each re-reading the [R, D] accumulator from HBM (traffic: 4·R·D·bytes vs
 the unfused 6·R·D).
 
-Tiling: grid over (rows, D // block_d); each program loads one row's lane
-block plus that row's scalar threshold, writes the kept and residual blocks
-once. block_d is a multiple of 128 to match the VPU lane width.
+Tiling: 1-D grid over D // block_d; each program loads every row's lane
+block ([R, block_d], as ``fused_update`` does) plus the [R] thresholds, and
+writes the kept and residual blocks once. A one-row block would break
+Mosaic's tiling rule (the second-minor block dim must be a multiple of 8 or
+span the whole array) whenever R > 1. block_d is a multiple of 128 to match
+the VPU lane width.
 """
 from __future__ import annotations
 
@@ -22,8 +25,8 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(acc_ref, thr_ref, sent_ref, resid_ref):
-    a = acc_ref[...].astype(jnp.float32)               # [1, block_d]
-    t = thr_ref[...].astype(jnp.float32)               # [1]
+    a = acc_ref[...].astype(jnp.float32)               # [R, block_d]
+    t = thr_ref[...].astype(jnp.float32)               # [R]
     keep = jnp.abs(a) >= t[:, None]
     sent = jnp.where(keep, a, 0.0)
     sent_ref[...] = sent.astype(sent_ref.dtype)
@@ -32,23 +35,17 @@ def _kernel(acc_ref, thr_ref, sent_ref, resid_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def sparsify_topk(acc: jax.Array, thr: jax.Array, block_d: int = 1024,
-                  interpret: bool = True):
+                  *, interpret: bool):
     """acc [R, D], thr [R] -> (sent [R, D], resid [R, D]). D % block_d == 0."""
     r, d = acc.shape
     assert thr.shape == (r,), thr.shape
     assert d % block_d == 0, f"D={d} must be a multiple of block_d={block_d}"
-    grid = (r, d // block_d)
+    rows = lambda: pl.BlockSpec((r, block_d), lambda j: (0, j))
     return pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-        ],
+        grid=(d // block_d,),
+        in_specs=[rows(), pl.BlockSpec((r,), lambda j: (0,))],
+        out_specs=[rows(), rows()],
         out_shape=[jax.ShapeDtypeStruct((r, d), acc.dtype),
                    jax.ShapeDtypeStruct((r, d), acc.dtype)],
         interpret=interpret,

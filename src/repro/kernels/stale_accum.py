@@ -28,7 +28,7 @@ def _kernel(params_ref, buffer_ref, weights_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def stale_accum(params: jax.Array, buffer: jax.Array, weights: jax.Array,
-                block_d: int = 1024, interpret: bool = True) -> jax.Array:
+                block_d: int = 1024, *, interpret: bool) -> jax.Array:
     """params [D], buffer [S, D], weights [S] -> [D]. D % block_d == 0."""
     (d,) = params.shape
     s = buffer.shape[0]

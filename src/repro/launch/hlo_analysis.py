@@ -98,8 +98,6 @@ def roofline(compiled, chips: int, hlo_text: Optional[str] = None,
     flops = parsed.flops
     hbm = parsed.bytes
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x: one dict per computation
-        ca = ca[0] if ca else {}
     coll = {k: int(v) for k, v in parsed.coll.items()}
     coll["count"] = collective_bytes(text)["count"]
     coll["xla_flops_unscaled"] = int(ca.get("flops", 0))

@@ -11,11 +11,10 @@ from repro.sharding.rules import data_extent  # noqa: F401  (single source)
 
 
 def _make_mesh(shape, axes):
-    # axis_types landed after jax 0.4.x; Auto is the default there anyway.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # The engine's plans place state through NamedShardings on Auto axes
+    # (jax.make_mesh defaults to Explicit).
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

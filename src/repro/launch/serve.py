@@ -21,7 +21,7 @@ import json
 
 import numpy as np
 
-from repro import configs as cfglib
+from repro import compile_cache
 from repro.serving import Request, Server, ServingConfig
 
 
@@ -55,6 +55,7 @@ def main():
                     help="max requests prefilled per jitted admission call "
                          "(default: the slot count)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = ServingConfig(
         arch=args.arch, reduced=args.reduced, slots=args.batch,

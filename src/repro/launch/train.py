@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs as cfglib
+from repro import compile_cache
 from repro import delays as delays_lib
 from repro import treemath as tm
 from repro.configs.base import InputShape
@@ -65,6 +66,22 @@ def make_batch_fn(api, batch: int, seq: int, seed: int, workers: int = 0):
         return out
 
     return next_batch
+
+
+def build_train_engine(api, arch, mesh, shape: InputShape, *,
+                       optimizer=None, lr=None, **engine_kw):
+    """The driver's engine: the arch's optimizer (fused Adam wherever the
+    kernel placement allows the packed view) under ``EngineConfig(
+    **engine_kw)``, planned on ``mesh`` for ``shape``."""
+    from repro.engine.api import kernel_placement_ok
+    opt_name = optimizer or arch.train_optimizer
+    opt_kwargs = {"lr": lr} if lr else {}
+    kernels = engine_kw.get("kernels", "off")
+    if opt_name == "adam" and kernel_placement_ok(kernels, arch, mesh)[0]:
+        opt_kwargs["kernel"] = True   # fused-Adam hot spot (opt-in)
+    opt = optlib.get_optimizer(opt_name, **opt_kwargs)
+    return build_engine(api, opt, EngineConfig(**engine_kw), mesh=mesh,
+                        arch=arch, shape=shape)
 
 
 def main():
@@ -117,6 +134,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.delay and args.trace:
         raise SystemExit("--delay and --trace are mutually exclusive "
@@ -143,21 +161,16 @@ def main():
     if mode != "sync" and args.batch % args.workers:
         raise SystemExit(f"mode={mode} needs --batch divisible by --workers")
     mesh = meshlib.parse_host_mesh(args.mesh)
-    opt_name = args.optimizer or arch.train_optimizer
-    opt_kwargs = {"lr": args.lr} if args.lr else {}
-    from repro.engine.api import kernel_placement_ok
-    if opt_name == "adam" and kernel_placement_ok(args.kernels, arch, mesh)[0]:
-        opt_kwargs["kernel"] = True   # fused-Adam hot spot (opt-in)
-    opt = optlib.get_optimizer(opt_name, **opt_kwargs)
     shape = InputShape(f"train_cli_{args.seq}", args.seq, args.batch, "train")
     if args.lr_scale == "theorem1" and not args.coherence:
         raise SystemExit("--lr-scale theorem1 takes its live mu/L signals "
                          "from the coherence probe: pass --coherence")
-    ecfg = EngineConfig(mode=mode, num_workers=args.workers, s=args.stale,
-                        delay=delay_spec, kernels=args.kernels,
-                        compress=args.compress, lr_scale=args.lr_scale,
-                        ssp_steps=max(args.steps, 1), ssp_seed=args.seed)
-    engine = build_engine(api, opt, ecfg, mesh=mesh, arch=arch, shape=shape)
+    engine = build_train_engine(
+        api, arch, mesh, shape, optimizer=args.optimizer, lr=args.lr,
+        mode=mode, num_workers=args.workers, s=args.stale, delay=delay_spec,
+        kernels=args.kernels, compress=args.compress,
+        lr_scale=args.lr_scale, ssp_steps=max(args.steps, 1),
+        ssp_seed=args.seed)
     state = engine.init(jax.random.PRNGKey(args.seed))
     n_params = tm.tree_size(engine.params(state))
     print(f"params: {n_params/1e6:.1f}M")

@@ -17,6 +17,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro import configs as cfglib
 from repro.checkpoint import checkpoint as ckpt
 from repro.engine import EngineConfig, Trainer, build_engine
@@ -28,6 +29,7 @@ ARCH = "deepseek-7b"
 
 
 def main() -> None:
+    compile_cache.enable()
     api = cfglib.get(ARCH).api(reduced=True)
     snap_dir = tempfile.mkdtemp(prefix="serving_smoke_")
 

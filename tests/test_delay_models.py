@@ -3,11 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # optional dep: deterministic fallback (see the shim)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import ssp as ssp_lib
 from repro.core.delay import (ConstantDelay, GeometricDelay, UniformDelay,

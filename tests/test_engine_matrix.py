@@ -73,8 +73,20 @@ def run_combo(engine, steps=2, seed=0):
     return state, losses
 
 
+def _rel_l2_close(a, b, tol=1e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    rel = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
+    assert rel <= tol, f"relative L2 {rel:.3g} > {tol} over {b.shape}"
+
+
 def check_legacy_equivalence(mesh, arch_id="deepseek-7b", steps=5):
-    """Engine-planned step == the pre-fold launch/steps.py path, bitwise."""
+    """Engine-planned step == the pre-fold launch/steps.py path: bitwise on
+    one device. On a mesh of several the sharded gradient mean reduces in
+    another order; Adam normalises each coordinate's step, so where a
+    gradient coordinate is near zero that fp32 noise can move that one
+    parameter by a few percent of a step (lr = 1e-3). There each leaf must
+    agree to 1e-5 in relative L2 norm: the noise measures under 4e-6, while
+    one parameter off by a whole step reads 5e-5 or more on every leaf."""
     P, s = 2, 3
     arch = cfglib.get(arch_id)
     api = arch.api(reduced=True)
@@ -100,12 +112,14 @@ def check_legacy_equivalence(mesh, arch_id="deepseek-7b", steps=5):
         state, em = engine.step(state, batch)
         np.testing.assert_array_equal(np.asarray(lm["mean_staleness"]),
                                       np.asarray(em["mean_staleness"]))
+    same = (np.testing.assert_array_equal if mesh.devices.size == 1
+            else _rel_l2_close)
     for a, b in zip(jax.tree.leaves(legacy.params),
                     jax.tree.leaves(state.inner.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        same(np.asarray(a), np.asarray(b))
     for a, b in zip(jax.tree.leaves(legacy.gbuf),
                     jax.tree.leaves(state.inner.gbuf)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        same(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("arch_id", ARCHS)

@@ -1,8 +1,8 @@
 """Kernel dispatch subsystem: packed flat views, backend routing, donation.
 
 Covers the tentpole contracts of the kernel-backed engine hot path:
-* ``treemath`` packed views round-trip exactly (property test via the
-  hypothesis shim), including leading worker axes and block padding;
+* ``treemath`` packed views round-trip exactly (hypothesis property
+  test), including leading worker axes and block padding;
 * the dispatchers agree with the ref oracles on divisible AND non-divisible
   D (the odd-shape path must fall back, not crash);
 * the packed stale delivery / fused Adam reproduce the per-leaf tree math
@@ -19,11 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # optional dep: deterministic fallback (see the shim)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro import treemath as tm
 from repro.core import stale_sync
@@ -197,6 +194,31 @@ def test_engine_kernels_on_rejects_fsdp_archs():
     eng = build_engine(quad_loss, optlib.sgd(0.1), cfg_auto,
                        arch="kimi-k2-1t-a32b")
     assert eng.meta["kernels"]["delivery"] == "tree"
+
+
+def test_compiled_kernels_vetoed_on_multi_device_mesh():
+    """XLA cannot partition a compiled Mosaic kernel: with the compiled
+    backend selected, a mesh of several devices gets no packed kernels;
+    one device, or the interpreter (plain HLO), keeps them."""
+    import dataclasses
+    import types
+    from repro.engine.api import kernel_placement_ok
+    two = types.SimpleNamespace(devices=np.empty((2, 1)),
+                                axis_names=("data", "model"))
+    one = types.SimpleNamespace(devices=np.empty((1, 1)),
+                                axis_names=("data", "model"))
+    old = dispatch.CONFIG
+    try:
+        dispatch.CONFIG = dataclasses.replace(old, interpret=False)
+        for kernels in ("auto", "on"):
+            ok, why = kernel_placement_ok(kernels, "deepseek-7b", two)
+            assert not ok and "partitioned" in why
+            assert kernel_placement_ok(kernels, "deepseek-7b", one) == (
+                True, "")
+        dispatch.CONFIG = dataclasses.replace(old, interpret=True)
+        assert kernel_placement_ok("auto", "deepseek-7b", two) == (True, "")
+    finally:
+        dispatch.CONFIG = old
 
 
 # -- donation ---------------------------------------------------------------

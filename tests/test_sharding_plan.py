@@ -120,6 +120,24 @@ def test_plan_round_trips_through_build_engine():
                for x, y in zip(sa, sb))
 
 
+def test_planned_engine_compiles_its_step_once():
+    """engine.init places the state as the step's outputs are placed, so
+    later steps reuse the first step's executable. An unplaced state is
+    another input type to jit, and the step compiled twice."""
+    from repro.configs.base import InputShape
+    from repro.launch.train import build_train_engine, make_batch_fn
+    arch = cfglib.get("deepseek-7b")
+    api = arch.api(reduced=True)
+    shape = InputShape("once_t", seq_len=16, global_batch=4, kind="train")
+    engine = build_train_engine(api, arch, host_mesh(), shape,
+                                mode="stale-psum", num_workers=2, s=1)
+    next_batch = make_batch_fn(api, 4, 16, 0)
+    state = engine.init(jax.random.PRNGKey(0))
+    for _ in range(3):
+        state, _ = engine.step(state, next_batch())
+    assert engine._jit_step._cache_size() == 1
+
+
 def test_batch_smaller_than_data_extent_replicates():
     """long_500k has global batch 1 < a multi-device data extent: the
     even-division fallback must drop the batch rule rather than emit an

@@ -5,11 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # optional dep: deterministic fallback (see the shim)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro import treemath as tm
 from repro.core import (ConstantDelay, StalenessConfig, UniformDelay, drain,
