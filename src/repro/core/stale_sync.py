@@ -36,6 +36,7 @@ from repro.delays.models import DelaySpec, UniformDelay, as_spec
 from repro.delays.schedule import Schedule
 from repro.kernels import dispatch
 from repro.optim.optimizers import Optimizer, lr_at
+from repro.scopes import MODEL, OPTIMIZER, RING
 
 Pytree = Any
 
@@ -199,8 +200,8 @@ def make_stale_train_step(
 
     def per_worker_grads(params, batch):
         def one(b):
-            loss, grads = jax.value_and_grad(loss_fn)(params, b)
-            return loss, grads
+            with jax.named_scope(MODEL):
+                return jax.value_and_grad(loss_fn)(params, b)
         shaped = jax.tree.map(
             lambda x: x.reshape((p, x.shape[0] // p) + x.shape[1:]), batch)
         return jax.vmap(one)(shaped)  # (losses [P], grads [P, ...])
@@ -231,27 +232,29 @@ def make_stale_train_step(
         moment/param update on the packed opt_state."""
         per = cfg.per_worker_delays
         slots = cfg.slots
-        write = jnp.mod(state.step, slots)
         spec = tm.pack_spec(state.params)
-        if cfg.s == 0:
-            d = jnp.zeros((p,) if per else (), jnp.int32)
-        else:
-            d = realized_delays(kdelay, state.step, bound,
-                                (p,) if per else ())
-        staleness = d if per else jnp.broadcast_to(d, (p,))
-        mean_stale = staleness.astype(jnp.float32).mean()
-        read = jnp.mod(state.step - d, slots)
+        with jax.named_scope(RING):
+            write = jnp.mod(state.step, slots)
+            if cfg.s == 0:
+                d = jnp.zeros((p,) if per else (), jnp.int32)
+            else:
+                d = realized_delays(kdelay, state.step, bound,
+                                    (p,) if per else ())
+            staleness = d if per else jnp.broadcast_to(d, (p,))
+            mean_stale = staleness.astype(jnp.float32).mean()
+            read = jnp.mod(state.step - d, slots)
 
         cmetrics = {}
-        factor = jnp.float32(1.0)
-        if compensator is not None and compensator.scales:
-            factor = compensator.lr_factor(comp, mean_stale, state.step)
-            cmetrics["lr_scale"] = factor
-        osp = optimizer.spec
-        ostep = state.opt_state["step"] + 1
-        eta = lr_at(osp["lr"], ostep)
-        m, v = state.opt_state["m"], state.opt_state["v"]
-        pzero = jnp.zeros_like(m)
+        with jax.named_scope(OPTIMIZER):
+            factor = jnp.float32(1.0)
+            if compensator is not None and compensator.scales:
+                factor = compensator.lr_factor(comp, mean_stale, state.step)
+                cmetrics["lr_scale"] = factor
+            osp = optimizer.spec
+            ostep = state.opt_state["step"] + 1
+            eta = lr_at(osp["lr"], ostep)
+            m, v = state.opt_state["m"], state.opt_state["v"]
+            pzero = jnp.zeros_like(m)
         adam_kw = dict(lr=eta, b1=osp["b1"], b2=osp["b2"], eps=osp["eps"],
                        step=ostep, scale=factor)
 
@@ -259,89 +262,81 @@ def make_stale_train_step(
             # Gather the PRE-write ring rows; the kernel substitutes this
             # step's sent for fresh (delay 0) rows, so the sparse payload
             # only has to reach the ring after the kernel.
-            acc, thr, mom_in = compensator.ef_inputs(comp, pack_grads(gtree),
-                                                     spec.total)
-            if per:
-                sel = _ring_rows(state.gbuf, read)
-                weights = jnp.full((p,), 1.0 / p, jnp.float32)
-            else:
-                sel = jax.lax.dynamic_index_in_dim(state.gbuf, read, 0,
-                                                   keepdims=True)
-                acc, thr = acc[None], jnp.reshape(thr, (1,))
-                mom_in = None if mom_in is None else mom_in[None]
-                weights = jnp.ones((1,), jnp.float32)
-            fresh = (d == 0).astype(jnp.float32).reshape(weights.shape)
-            outs = dispatch.fused_update(pzero, m, v, sel, weights,
-                                         acc=acc, thr=thr, fresh=fresh,
-                                         mom=mom_in, **adam_kw)
+            with jax.named_scope(RING):
+                acc, thr, mom_in = compensator.ef_inputs(
+                    comp, pack_grads(gtree), spec.total)
+                if per:
+                    sel = _ring_rows(state.gbuf, read)
+                    weights = jnp.full((p,), 1.0 / p, jnp.float32)
+                else:
+                    sel = jax.lax.dynamic_index_in_dim(state.gbuf, read, 0,
+                                                       keepdims=True)
+                    acc, thr = acc[None], jnp.reshape(thr, (1,))
+                    mom_in = None if mom_in is None else mom_in[None]
+                    weights = jnp.ones((1,), jnp.float32)
+                fresh = (d == 0).astype(jnp.float32).reshape(weights.shape)
+            with jax.named_scope(OPTIMIZER):
+                outs = dispatch.fused_update(pzero, m, v, sel, weights,
+                                             acc=acc, thr=thr, fresh=fresh,
+                                             mom=mom_in, **adam_kw)
             dneg, m2, v2, u, sent, resid = outs[:6]
             mom_out = outs[6] if mom_in is not None else None
-            comp = compensator.ef_commit(
-                comp, resid if per else resid[0],
-                mom_out if (per or mom_out is None) else mom_out[0])
-            cmetrics.update(compensator.ef_metrics(sent, spec.total))
-            payload = sent if per else sent[0]
-            gbuf = jax.lax.dynamic_update_index_in_dim(
-                state.gbuf, payload.astype(state.gbuf.dtype), write, 0)
+            with jax.named_scope(RING):
+                comp = compensator.ef_commit(
+                    comp, resid if per else resid[0],
+                    mom_out if (per or mom_out is None) else mom_out[0])
+                cmetrics.update(compensator.ef_metrics(sent, spec.total))
+                payload = sent if per else sent[0]
+                gbuf = jax.lax.dynamic_update_index_in_dim(
+                    state.gbuf, payload.astype(state.gbuf.dtype), write, 0)
         else:
             # Dense: the ring write happens first and the gather reads the
             # written ring (fresh rows come back verbatim) — the same
             # write-then-read order as the three-dispatch path.
-            gbuf = jax.lax.dynamic_update_index_in_dim(
-                state.gbuf, pack_grads(gtree, state.gbuf.dtype), write, 0)
-            if per:
-                sel = _ring_rows(gbuf, read)
-                weights = jnp.full((p,), 1.0 / p, jnp.float32)
-            else:
-                sel = jax.lax.dynamic_index_in_dim(gbuf, read, 0,
-                                                   keepdims=True)
-                weights = jnp.ones((1,), jnp.float32)
-            dneg, m2, v2, u = dispatch.fused_update(pzero, m, v, sel,
-                                                    weights, **adam_kw)
+            with jax.named_scope(RING):
+                gbuf = jax.lax.dynamic_update_index_in_dim(
+                    state.gbuf, pack_grads(gtree, state.gbuf.dtype), write,
+                    0)
+                if per:
+                    sel = _ring_rows(gbuf, read)
+                    weights = jnp.full((p,), 1.0 / p, jnp.float32)
+                else:
+                    sel = jax.lax.dynamic_index_in_dim(gbuf, read, 0,
+                                                       keepdims=True)
+                    weights = jnp.ones((1,), jnp.float32)
+            with jax.named_scope(OPTIMIZER):
+                dneg, m2, v2, u = dispatch.fused_update(pzero, m, v, sel,
+                                                        weights, **adam_kw)
 
-        delta32 = tm.tree_unpack(dneg, spec, dtype=jnp.float32)
-        wd = osp["weight_decay"]
-        swd = factor * eta * wd if wd else None
+        with jax.named_scope(OPTIMIZER):
+            delta32 = tm.tree_unpack(dneg, spec, dtype=jnp.float32)
+            wd = osp["weight_decay"]
+            swd = factor * eta * wd if wd else None
 
-        def delta_leaf(dl, pp):
-            if swd is not None:
-                dl = dl - swd * pp
-            return dl.astype(pp.dtype)
+            def delta_leaf(dl, pp):
+                if swd is not None:
+                    dl = dl - swd * pp
+                return dl.astype(pp.dtype)
 
-        delta = jax.tree.map(delta_leaf, delta32, state.params)
-        params = tm.tree_add(state.params, delta)
-        new_state = StaleTrainState(
-            params=params, opt_state={"step": ostep, "m": m2, "v": v2},
-            gbuf=gbuf, step=state.step + 1, key=key)
-        metrics = {
-            "loss": losses.mean(),
-            "grad_norm": jnp.sqrt(jnp.sum(u * u)),
-            "mean_staleness": mean_stale,
-            **cmetrics,
-        }
+            delta = jax.tree.map(delta_leaf, delta32, state.params)
+            params = tm.tree_add(state.params, delta)
+            new_state = StaleTrainState(
+                params=params, opt_state={"step": ostep, "m": m2, "v": v2},
+                gbuf=gbuf, step=state.step + 1, key=key)
+            metrics = {
+                "loss": losses.mean(),
+                "grad_norm": jnp.sqrt(jnp.sum(u * u)),
+                "mean_staleness": mean_stale,
+                **cmetrics,
+            }
         if compensator is not None:
             return new_state, comp, metrics
         return new_state, metrics
 
-    def step(state: StaleTrainState, batch,
-             bound: Optional[jax.Array] = None,
-             comp: Pytree = None) -> Tuple[StaleTrainState, dict]:
-        key, kdelay = jax.random.split(state.key)
-        if cfg.per_worker_delays:
-            losses, grads = per_worker_grads(state.params, batch)
-        else:
-            # Aggregate form needs only the global mean gradient — one
-            # backward pass, not P vmapped ones (mathematically identical;
-            # measured 14x less collective traffic on the FSDP 1T config,
-            # whose per-worker backwards each re-gathered the params).
-            loss, gmean = jax.value_and_grad(loss_fn)(state.params, batch)
-            losses = loss[None]
-            grads = None
-        if cfg.fused_update:
-            return fused_tail(state, losses,
-                              grads if cfg.per_worker_delays else gmean,
-                              kdelay, key, bound, comp)
-
+    def deliver(state, grads, gmean, kdelay, bound, comp):
+        """Ring write, delayed read and worker mean of this step's
+        gradients (``grads`` per worker, or the aggregate ``gmean``):
+        ``(gbuf, agg, mean_stale, comp, cmetrics)``."""
         slots = cfg.slots
         write = jnp.mod(state.step, slots)
         # Compression runs per SOURCE, before the ring write (pre-transport:
@@ -436,23 +431,52 @@ def make_stale_train_step(
             staleness = jnp.broadcast_to(d, (p,))
 
         mean_stale = staleness.astype(jnp.float32).mean()
-        comp = comp_box[0]
-        delta, opt_state = optimizer.update(agg, state.opt_state, state.params)
-        if compensator is not None and compensator.scales:
-            factor = compensator.lr_factor(comp, mean_stale, state.step)
-            delta = compensator.scale_tree(delta, factor)
-            cmetrics["lr_scale"] = factor
-        params = tm.tree_add(state.params, delta)
+        return gbuf, agg, mean_stale, comp_box[0], cmetrics
 
-        new_state = StaleTrainState(
-            params=params, opt_state=opt_state, gbuf=gbuf,
-            step=state.step + 1, key=key)
-        metrics = {
-            "loss": losses.mean(),
-            "grad_norm": tm.tree_norm(agg),
-            "mean_staleness": mean_stale,
-            **cmetrics,
-        }
+    def step(state: StaleTrainState, batch,
+             bound: Optional[jax.Array] = None,
+             comp: Pytree = None) -> Tuple[StaleTrainState, dict]:
+        with jax.named_scope(RING):
+            key, kdelay = jax.random.split(state.key)
+        if cfg.per_worker_delays:
+            losses, grads = per_worker_grads(state.params, batch)
+            gmean = None
+        else:
+            # Aggregate form needs only the global mean gradient — one
+            # backward pass, not P vmapped ones (mathematically identical;
+            # measured 14x less collective traffic on the FSDP 1T config,
+            # whose per-worker backwards each re-gathered the params).
+            with jax.named_scope(MODEL):
+                loss, gmean = jax.value_and_grad(loss_fn)(state.params,
+                                                          batch)
+            losses = loss[None]
+            grads = None
+        if cfg.fused_update:
+            return fused_tail(state, losses,
+                              grads if cfg.per_worker_delays else gmean,
+                              kdelay, key, bound, comp)
+
+        with jax.named_scope(RING):
+            gbuf, agg, mean_stale, comp, cmetrics = deliver(
+                state, grads, gmean, kdelay, bound, comp)
+        with jax.named_scope(OPTIMIZER):
+            delta, opt_state = optimizer.update(agg, state.opt_state,
+                                                state.params)
+            if compensator is not None and compensator.scales:
+                factor = compensator.lr_factor(comp, mean_stale, state.step)
+                delta = compensator.scale_tree(delta, factor)
+                cmetrics["lr_scale"] = factor
+            params = tm.tree_add(state.params, delta)
+
+            new_state = StaleTrainState(
+                params=params, opt_state=opt_state, gbuf=gbuf,
+                step=state.step + 1, key=key)
+            metrics = {
+                "loss": losses.mean(),
+                "grad_norm": tm.tree_norm(agg),
+                "mean_staleness": mean_stale,
+                **cmetrics,
+            }
         if compensator is not None:
             return new_state, comp, metrics
         return new_state, metrics
@@ -464,13 +488,17 @@ def make_sync_train_step(loss_fn, optimizer: Optimizer):
     """Plain synchronous data-parallel step (the 40-pair dry-run baseline)."""
 
     def step(state: StaleTrainState, batch) -> Tuple[StaleTrainState, dict]:
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        delta, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = tm.tree_add(state.params, delta)
-        new_state = StaleTrainState(
-            params=params, opt_state=opt_state, gbuf=state.gbuf,
-            step=state.step + 1, key=state.key)
-        return new_state, {"loss": loss, "grad_norm": tm.tree_norm(grads)}
+        with jax.named_scope(MODEL):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+        with jax.named_scope(OPTIMIZER):
+            delta, opt_state = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            params = tm.tree_add(state.params, delta)
+            new_state = StaleTrainState(
+                params=params, opt_state=opt_state, gbuf=state.gbuf,
+                step=state.step + 1, key=state.key)
+            return new_state, {"loss": loss,
+                               "grad_norm": tm.tree_norm(grads)}
 
     return step
 
@@ -587,23 +615,28 @@ def make_sync_train_step_lean(loss_fn, optimizer: Optimizer,
         return new_state, {"loss": loss}
 
     def step(state: SyncTrainState, batch, comp: Pytree = None):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
+        with jax.named_scope(MODEL):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
         # _sync_fuses is trace-time static (width + dispatch config), and
         # init_sync_state applies the same predicate — layouts agree.
         if fused and _sync_fuses(state.params):
-            return fused_tail(state, loss, grads, comp)
+            with jax.named_scope(OPTIMIZER):
+                return fused_tail(state, loss, grads, comp)
         cmetrics = {}
         if compensator is not None:
             # See the fused tail's note: sync is the s=0 reference point.
             grads, comp, cmetrics = compensator.sparsify_tree(comp, grads)
-        delta, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        if compensator is not None and compensator.scales:
-            factor = compensator.lr_factor(comp, jnp.float32(0.0), state.step)
-            delta = compensator.scale_tree(delta, factor)
-            cmetrics = {**cmetrics, "lr_scale": factor}
-        params = tm.tree_add(state.params, delta)
-        new_state = SyncTrainState(params=params, opt_state=opt_state,
-                                   step=state.step + 1)
+        with jax.named_scope(OPTIMIZER):
+            delta, opt_state = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            if compensator is not None and compensator.scales:
+                factor = compensator.lr_factor(comp, jnp.float32(0.0),
+                                               state.step)
+                delta = compensator.scale_tree(delta, factor)
+                cmetrics = {**cmetrics, "lr_scale": factor}
+            params = tm.tree_add(state.params, delta)
+            new_state = SyncTrainState(params=params, opt_state=opt_state,
+                                       step=state.step + 1)
         if compensator is not None:
             return new_state, comp, {"loss": loss, **cmetrics}
         return new_state, {"loss": loss}

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 
 from repro import treemath as tm
 from repro.delays.models import DelayModel, DelaySpec, UniformDelay, as_spec
+from repro.scopes import MODEL, OPTIMIZER, RING
 
 Pytree = Any
 # update_fn(params, update_state, batch, key) -> (update, new_update_state, metrics)
@@ -258,7 +259,6 @@ def make_sim_step(
                     bound: Optional[jax.Array] = None,
                     comp: Pytree = None) -> Tuple[SimState, dict]:
         from repro.kernels import dispatch
-        key, kdelay, kupd = jax.random.split(state.key, 3)
         pspec = tm.pack_spec(state.caches, lead_ndim=1)
         ring = state.pending["ring"]
 
@@ -266,16 +266,18 @@ def make_sim_step(
         #    module docstring): one fused accumulate over the flattened
         #    packed caches view, the same stale_accum hot spot as the
         #    gradient ring.
-        arrived = state.pending["arrived"]                       # [P, D]
-        cvec = tm.tree_pack(state.caches, lead_ndim=1,
-                            pad_to=dispatch.PACK_ALIGN)          # [P, D] fp32
-        flat = dispatch.stale_accum(cvec.reshape(-1),
-                                    arrived.reshape(1, -1),
-                                    jnp.ones((1,), jnp.float32))
-        caches = tm.tree_unpack(flat.reshape(p, -1), pspec)
+        with jax.named_scope(RING):
+            key, kdelay, kupd = jax.random.split(state.key, 3)
+            arrived = state.pending["arrived"]                   # [P, D]
+            cvec = tm.tree_pack(state.caches, lead_ndim=1,
+                                pad_to=dispatch.PACK_ALIGN)      # [P, D] fp32
+            flat = dispatch.stale_accum(cvec.reshape(-1),
+                                        arrived.reshape(1, -1),
+                                        jnp.ones((1,), jnp.float32))
+            caches = tm.tree_unpack(flat.reshape(p, -1), pspec)
+            worker_keys = _worker_keys(kupd, caches)
 
         # 2. compute (identical to the tree path).
-        worker_keys = _worker_keys(kupd, caches)
         updates, update_state, metrics = jax.vmap(update_fn)(
             caches, state.update_state, batches, worker_keys)
 
@@ -284,25 +286,27 @@ def make_sim_step(
         #    the NEXT step's arrivals. The prefetch reads the ring after
         #    every write (a true dependency), so the donated ring mutates
         #    strictly in place.
-        delays = source.delays(kdelay, state.step, (p, p))
-        if bound is not None:
-            delays = jnp.minimum(delays, jnp.asarray(bound, jnp.int32))
-        uvec = tm.tree_pack(updates, lead_ndim=1,
-                            pad_to=dispatch.PACK_ALIGN)          # [P, D]
+        with jax.named_scope(RING):
+            delays = source.delays(kdelay, state.step, (p, p))
+            if bound is not None:
+                delays = jnp.minimum(delays, jnp.asarray(bound, jnp.int32))
+            uvec = tm.tree_pack(updates, lead_ndim=1,
+                                pad_to=dispatch.PACK_ALIGN)      # [P, D]
         if compensator is not None:
             uvec, comp, cmetrics = compensate(
                 comp, uvec, delays, state.step, packed_true_size=pspec.total)
             metrics = {**metrics, **cmetrics}
-        cursor = jnp.mod(state.step, slots)
-        ring = jax.lax.dynamic_update_index_in_dim(
-            ring, jnp.zeros_like(arrived)[:, None], cursor, axis=1)
-        slot = jnp.mod(state.step + 1 + delays, slots)           # [src, dst]
-        dst = jnp.broadcast_to(jnp.arange(p)[None, :], (p, p))
-        ring = ring.at[dst, slot].add(
-            jnp.broadcast_to(uvec[:, None, :], (p, p) + uvec.shape[-1:])
-            .astype(ring.dtype))
-        arrived_next = jax.lax.dynamic_index_in_dim(
-            ring, jnp.mod(state.step + 1, slots), axis=1, keepdims=False)
+        with jax.named_scope(RING):
+            cursor = jnp.mod(state.step, slots)
+            ring = jax.lax.dynamic_update_index_in_dim(
+                ring, jnp.zeros_like(arrived)[:, None], cursor, axis=1)
+            slot = jnp.mod(state.step + 1 + delays, slots)       # [src, dst]
+            dst = jnp.broadcast_to(jnp.arange(p)[None, :], (p, p))
+            ring = ring.at[dst, slot].add(
+                jnp.broadcast_to(uvec[:, None, :], (p, p) + uvec.shape[-1:])
+                .astype(ring.dtype))
+            arrived_next = jax.lax.dynamic_index_in_dim(
+                ring, jnp.mod(state.step + 1, slots), axis=1, keepdims=False)
 
         new_state = SimState(
             caches=caches,
@@ -318,68 +322,73 @@ def make_sim_step(
                           comp: Pytree = None) -> Tuple[SimState, dict]:
         from repro.kernels import dispatch
         from repro.optim.optimizers import lr_at
-        key, kdelay, kupd = jax.random.split(state.key, 3)
         pspec = tm.pack_spec(state.caches, lead_ndim=1)
         ring = state.pending["ring"]
 
         # 1. deliver (identical to packed_step).
-        arrived = state.pending["arrived"]                       # [P, D]
-        cvec = tm.tree_pack(state.caches, lead_ndim=1,
-                            pad_to=dispatch.PACK_ALIGN)          # [P, D] fp32
-        flat = dispatch.stale_accum(cvec.reshape(-1),
-                                    arrived.reshape(1, -1),
-                                    jnp.ones((1,), jnp.float32))
-        cflat = flat.reshape(p, -1)                              # [P, D]
-        caches = tm.tree_unpack(cflat, pspec)
+        with jax.named_scope(RING):
+            key, kdelay, kupd = jax.random.split(state.key, 3)
+            arrived = state.pending["arrived"]                   # [P, D]
+            cvec = tm.tree_pack(state.caches, lead_ndim=1,
+                                pad_to=dispatch.PACK_ALIGN)      # [P, D] fp32
+            flat = dispatch.stale_accum(cvec.reshape(-1),
+                                        arrived.reshape(1, -1),
+                                        jnp.ones((1,), jnp.float32))
+            cflat = flat.reshape(p, -1)                          # [P, D]
+            caches = tm.tree_unpack(cflat, pspec)
+            worker_keys = _worker_keys(kupd, caches)
 
         # 2. compute: per-worker gradients, then ALL P Adam updates in one
         #    fused pass over the flattened packed view. The moments stay
         #    packed in update_state ([P, D] fp32), read/written exactly
         #    once; the delta rows ARE the packed transport payload.
-        worker_keys = _worker_keys(kupd, caches)
-
         def grad_one(cache, batch, wkey):
-            if fused["takes_key"]:
-                return jax.value_and_grad(fused["loss"])(cache, batch, wkey)
-            return jax.value_and_grad(fused["loss"])(cache, batch)
+            args = (cache, batch, wkey) if fused["takes_key"] else (cache,
+                                                                     batch)
+            with jax.named_scope(MODEL):
+                return jax.value_and_grad(fused["loss"])(*args)
 
         losses, grads = jax.vmap(grad_one)(caches, batches, worker_keys)
-        gvec = tm.tree_pack(grads, lead_ndim=1,
-                            pad_to=dispatch.PACK_ALIGN)          # [P, D]
-        m, v = state.update_state["m"], state.update_state["v"]
-        ostep = state.step + 1        # every worker steps once per iteration
-        eta = lr_at(fused["lr"], ostep)
-        dneg, m2, v2 = dispatch.fused_adam(
-            jnp.zeros((m.size,), jnp.float32), m.reshape(-1), v.reshape(-1),
-            gvec.reshape(-1), eta, fused["b1"], fused["b2"], fused["eps"],
-            ostep)
-        uvec = dneg.reshape(p, -1)                               # [P, D]
-        wd = fused["weight_decay"]
-        if wd:
-            # Decoupled decay against the post-delivery cache each gradient
-            # was computed at — the packed image of the per-leaf AdamW rule.
-            uvec = uvec - eta * wd * cflat
+        with jax.named_scope(OPTIMIZER):
+            gvec = tm.tree_pack(grads, lead_ndim=1,
+                                pad_to=dispatch.PACK_ALIGN)      # [P, D]
+            m, v = state.update_state["m"], state.update_state["v"]
+            ostep = state.step + 1    # every worker steps once per iteration
+            eta = lr_at(fused["lr"], ostep)
+            dneg, m2, v2 = dispatch.fused_adam(
+                jnp.zeros((m.size,), jnp.float32), m.reshape(-1),
+                v.reshape(-1), gvec.reshape(-1), eta, fused["b1"],
+                fused["b2"], fused["eps"], ostep)
+            uvec = dneg.reshape(p, -1)                           # [P, D]
+            wd = fused["weight_decay"]
+            if wd:
+                # Decoupled decay against the post-delivery cache each
+                # gradient was computed at — the packed image of the
+                # per-leaf AdamW rule.
+                uvec = uvec - eta * wd * cflat
         update_state = {"m": m2.reshape(p, -1), "v": v2.reshape(p, -1)}
         metrics = {"loss": losses}
 
         # 3. dispatch (identical to packed_step).
-        delays = source.delays(kdelay, state.step, (p, p))
-        if bound is not None:
-            delays = jnp.minimum(delays, jnp.asarray(bound, jnp.int32))
+        with jax.named_scope(RING):
+            delays = source.delays(kdelay, state.step, (p, p))
+            if bound is not None:
+                delays = jnp.minimum(delays, jnp.asarray(bound, jnp.int32))
         if compensator is not None:
             uvec, comp, cmetrics = compensate(
                 comp, uvec, delays, state.step, packed_true_size=pspec.total)
             metrics = {**metrics, **cmetrics}
-        cursor = jnp.mod(state.step, slots)
-        ring = jax.lax.dynamic_update_index_in_dim(
-            ring, jnp.zeros_like(arrived)[:, None], cursor, axis=1)
-        slot = jnp.mod(state.step + 1 + delays, slots)           # [src, dst]
-        dst = jnp.broadcast_to(jnp.arange(p)[None, :], (p, p))
-        ring = ring.at[dst, slot].add(
-            jnp.broadcast_to(uvec[:, None, :], (p, p) + uvec.shape[-1:])
-            .astype(ring.dtype))
-        arrived_next = jax.lax.dynamic_index_in_dim(
-            ring, jnp.mod(state.step + 1, slots), axis=1, keepdims=False)
+        with jax.named_scope(RING):
+            cursor = jnp.mod(state.step, slots)
+            ring = jax.lax.dynamic_update_index_in_dim(
+                ring, jnp.zeros_like(arrived)[:, None], cursor, axis=1)
+            slot = jnp.mod(state.step + 1 + delays, slots)       # [src, dst]
+            dst = jnp.broadcast_to(jnp.arange(p)[None, :], (p, p))
+            ring = ring.at[dst, slot].add(
+                jnp.broadcast_to(uvec[:, None, :], (p, p) + uvec.shape[-1:])
+                .astype(ring.dtype))
+            arrived_next = jax.lax.dynamic_index_in_dim(
+                ring, jnp.mod(state.step + 1, slots), axis=1, keepdims=False)
 
         new_state = SimState(
             caches=caches,
@@ -393,39 +402,43 @@ def make_sim_step(
     def step(state: SimState, batches: Pytree,
              bound: Optional[jax.Array] = None,
              comp: Pytree = None) -> Tuple[SimState, dict]:
-        key, kdelay, kupd = jax.random.split(state.key, 3)
-
         # 1. deliver arrivals scheduled for this iteration.
-        if cfg.server_side:
-            arrived = jax.tree.map(lambda b: b[:, 0], state.pending)
-            caches, server_state = jax.vmap(server_apply)(
-                state.caches, state.server_state, arrived
-            )
-            pending = jax.tree.map(
-                lambda b: jnp.concatenate([b[:, 1:], jnp.zeros_like(b[:, :1])], axis=1),
-                state.pending,
-            )
-        else:
-            caches, pending = _deliver(state.caches, state.pending)
-            server_state = state.server_state
+        with jax.named_scope(RING):
+            key, kdelay, kupd = jax.random.split(state.key, 3)
+            if cfg.server_side:
+                arrived = jax.tree.map(lambda b: b[:, 0], state.pending)
+                with jax.named_scope(OPTIMIZER):
+                    caches, server_state = jax.vmap(server_apply)(
+                        state.caches, state.server_state, arrived
+                    )
+                pending = jax.tree.map(
+                    lambda b: jnp.concatenate(
+                        [b[:, 1:], jnp.zeros_like(b[:, :1])], axis=1),
+                    state.pending,
+                )
+            else:
+                caches, pending = _deliver(state.caches, state.pending)
+                server_state = state.server_state
+            worker_keys = _worker_keys(kupd, caches)
 
         # 2. every worker computes its update from its own (stale) cache.
-        worker_keys = _worker_keys(kupd, caches)
         updates, update_state, metrics = jax.vmap(update_fn)(
             caches, state.update_state, batches, worker_keys
         )
 
         # 3. dispatch into the delivery buffer with the realized delays.
-        delays = source.delays(kdelay, state.step, (p, p))
-        if bound is not None:
-            # Dynamic staleness control (repro.engine): clamp the sampled
-            # delay to an (inclusive, possibly traced) runtime bound.
-            delays = jnp.minimum(delays, jnp.asarray(bound, jnp.int32))
+        with jax.named_scope(RING):
+            delays = source.delays(kdelay, state.step, (p, p))
+            if bound is not None:
+                # Dynamic staleness control (repro.engine): clamp the
+                # sampled delay to an (inclusive, possibly traced) bound.
+                delays = jnp.minimum(delays, jnp.asarray(bound, jnp.int32))
         if compensator is not None:
             updates, comp, cmetrics = compensate(
                 comp, updates, delays, state.step)
             metrics = {**metrics, **cmetrics}
-        pending = _dispatch(pending, updates, delays, slots)
+        with jax.named_scope(RING):
+            pending = _dispatch(pending, updates, delays, slots)
 
         new_state = SimState(
             caches=caches,

@@ -32,13 +32,14 @@ trajectories are reproduced bit-for-bit (tested in test_engine_api.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import compensate as compensate_lib
 from repro import delays as delays_lib
+from repro import scopes
 from repro.core import ssp as ssp_lib
 from repro.core import stale_sync, staleness
 from repro.delays.models import DelaySpec, UniformDelay
@@ -254,6 +255,30 @@ class Engine:
     def lowered_step(self):
         """Lower one sharded step on the engine's mesh (dry-run entry)."""
         return self.plan().lower(self.mesh)
+
+    def compiled_step_text(self, state: EngineState, batch) -> str:
+        """The compiled step's HLO text. ``state`` and ``batch`` may be
+        arrays or ``jax.ShapeDtypeStruct``s (``engine.plan().args``).
+
+        The persistent compilation cache keys a program without its
+        metadata, so an executable loaded from it may carry the op names of
+        another build of the same program; this compile keys on the
+        metadata too, and its text names this build's scopes."""
+        flag = "jax_compilation_cache_include_metadata_in_key"
+        prev = getattr(jax.config, flag)
+        jax.config.update(flag, True)
+        try:
+            return self._jit_step.lower(state, batch).compile().as_text()
+        finally:
+            jax.config.update(flag, prev)
+
+    def op_layers(self, state: EngineState, batch) -> Dict[str, str]:
+        """Every instruction of the compiled step, nested computations
+        included, mapped to ``forward``, ``backward``, ``ring``,
+        ``optimizer`` or ``other`` by the named scope it was traced under
+        (``repro.scopes``). A device trace names each operation by its
+        instruction, so this map reads a trace by layer."""
+        return scopes.op_layers(self.compiled_step_text(state, batch))
 
     # -- views -------------------------------------------------------------
     def params(self, state: EngineState) -> Pytree:

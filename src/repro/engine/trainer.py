@@ -13,6 +13,7 @@ import time
 from typing import Any, Callable, Iterable, List, Optional, Sequence
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.engine.api import Engine, EngineState
 
@@ -78,6 +79,13 @@ class Trainer:
         ``higher_better``) and reports worker-batches-to-target — the
         paper's primary measurement.  ``log_every`` emits metric rows that
         hooks (sinks) can consume.
+
+        Each iteration is a profiler step span ``train`` (its ``step_num``
+        the 0-based step), holding ``trainer.batch`` (the batch source),
+        ``trainer.dispatch`` (``engine.step``), ``trainer.hooks`` (the
+        ``on_step`` fan-out), ``trainer.log`` (the log row, where the host
+        syncs) and ``trainer.eval``. Spans cost a few microseconds and are
+        recorded only while a profiler runs.
         """
         engine = self.engine
         if state is None:
@@ -90,7 +98,7 @@ class Trainer:
         for h in self.hooks:
             h.on_start(ctx)
 
-        t0 = time.time()
+        t0 = time.monotonic()
         history: List[dict] = []
         curve: list = []
         batches_to_target, converged = None, False
@@ -101,62 +109,78 @@ class Trainer:
         # whatever the delay process happened to do on log-interval steps.
         stale_sum, stale_n = 0.0, 0
         for t in range(steps):
-            try:
-                batch = next_batch()
-            except StopIteration:  # finite source exhausted: end gracefully
-                break
-            state, metrics = engine.step(ctx.state, batch)
-            ctx.state, ctx.step, ctx.metrics, ctx.row = state, t, metrics, None
-            if "mean_staleness" in metrics:
-                stale_sum = stale_sum + metrics["mean_staleness"]
-                stale_n += 1
-            for h in self.hooks:
-                h.on_step(ctx)
-
-            if log_every and (t + 1) % log_every == 0:
-                ctx.row = {"step": t + 1,
-                           "wall_s": round(time.time() - t0, 2)}
-                if "loss" in metrics:
-                    ctx.row["loss"] = float(metrics["loss"])
-                if "mean_staleness" in metrics:
-                    ctx.row["mean_staleness"] = float(
-                        metrics["mean_staleness"])
-                    # Realized mean TOTAL delay (1 + r) over ALL steps so
-                    # far — sweeps verify a delay spec's effective staleness
-                    # against its nominal spec.mean_total_delay.
-                    ctx.row["mean_total_delay"] = round(
-                        1.0 + float(stale_sum) / stale_n, 4)
-                # Compensation diagnostics (repro.compensate): realized
-                # sparsity and the effective stepsize factor, beside the
-                # realized delay they compensate.
-                if "sparsity" in metrics:
-                    ctx.row["sparsity"] = round(float(metrics["sparsity"]), 4)
-                if "lr_scale" in metrics:
-                    ctx.row["lr_scale"] = round(
-                        float(jax.numpy.mean(metrics["lr_scale"])), 6)
-                if engine._max_bound:
-                    # live dynamic staleness bound (coherence-controller lever)
-                    ctx.row["bound"] = int(jax.device_get(ctx.state.bound))
-                for h in self.hooks:
-                    h.on_log(ctx)
-                history.append(ctx.row)
-
-            if eval_jit is not None and eval_every and (t + 1) % eval_every == 0:
-                value = float(eval_jit(engine.params(ctx.state)))
-                worker_batches = (t + 1) * engine.batches_per_step
-                curve.append((worker_batches, value))
-                for h in self.hooks:
-                    h.on_eval(ctx, value)
-                if target is not None:
-                    hit = value >= target if higher_better else value <= target
-                    if hit:
-                        batches_to_target, converged = worker_batches, True
+            with StepTraceAnnotation("train", step_num=t):
+                with TraceAnnotation("trainer.batch"):
+                    try:
+                        batch = next_batch()
+                    except StopIteration:  # finite source exhausted
                         break
+                with TraceAnnotation("trainer.dispatch"):
+                    state, metrics = engine.step(ctx.state, batch)
+                ctx.state, ctx.step, ctx.metrics, ctx.row = (state, t,
+                                                             metrics, None)
+                if "mean_staleness" in metrics:
+                    stale_sum = stale_sum + metrics["mean_staleness"]
+                    stale_n += 1
+                with TraceAnnotation("trainer.hooks"):
+                    for h in self.hooks:
+                        h.on_step(ctx)
+
+                if log_every and (t + 1) % log_every == 0:
+                    with TraceAnnotation("trainer.log"):
+                        history.append(self._log_row(
+                            ctx, t, t0, stale_sum, stale_n))
+
+                if (eval_jit is not None and eval_every
+                        and (t + 1) % eval_every == 0):
+                    with TraceAnnotation("trainer.eval"):
+                        value = float(eval_jit(engine.params(ctx.state)))
+                        worker_batches = (t + 1) * engine.batches_per_step
+                        curve.append((worker_batches, value))
+                        for h in self.hooks:
+                            h.on_eval(ctx, value)
+                    if target is not None:
+                        hit = (value >= target if higher_better
+                               else value <= target)
+                        if hit:
+                            batches_to_target, converged = (worker_batches,
+                                                            True)
+                            break
 
         result = TrainResult(
             state=ctx.state, history=history, curve=curve,
             batches_to_target=batches_to_target, converged=converged,
-            wall_s=time.time() - t0)
+            wall_s=time.monotonic() - t0)
         for h in self.hooks:
             h.on_end(ctx, result)
         return result
+
+    def _log_row(self, ctx: StepContext, t: int, t0: float, stale_sum,
+                 stale_n: int) -> dict:
+        """Assemble step ``t``'s log row (the host syncs on the metrics
+        here) and hand it to the ``on_log`` hooks."""
+        metrics = ctx.metrics
+        ctx.row = {"step": t + 1, "wall_s": round(time.monotonic() - t0, 2)}
+        if "loss" in metrics:
+            ctx.row["loss"] = float(metrics["loss"])
+        if "mean_staleness" in metrics:
+            ctx.row["mean_staleness"] = float(metrics["mean_staleness"])
+            # Realized mean TOTAL delay (1 + r) over ALL steps so far —
+            # sweeps verify a delay spec's effective staleness against its
+            # nominal spec.mean_total_delay.
+            ctx.row["mean_total_delay"] = round(
+                1.0 + float(stale_sum) / stale_n, 4)
+        # Compensation diagnostics (repro.compensate): realized sparsity and
+        # the effective stepsize factor, beside the realized delay they
+        # compensate.
+        if "sparsity" in metrics:
+            ctx.row["sparsity"] = round(float(metrics["sparsity"]), 4)
+        if "lr_scale" in metrics:
+            ctx.row["lr_scale"] = round(
+                float(jax.numpy.mean(metrics["lr_scale"])), 6)
+        if self.engine._max_bound:
+            # live dynamic staleness bound (coherence-controller lever)
+            ctx.row["bound"] = int(jax.device_get(ctx.state.bound))
+        for h in self.hooks:
+            h.on_log(ctx)
+        return ctx.row

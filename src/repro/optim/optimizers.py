@@ -18,6 +18,8 @@ from typing import Any, Callable, NamedTuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import MODEL, OPTIMIZER
+
 Pytree = Any
 Schedule = Union[float, Callable[[jax.Array], jax.Array]]
 
@@ -208,8 +210,10 @@ def make_sgd_update_fn(loss_fn, optimizer: Optimizer):
     """Adapt (loss_fn, optimizer) to the staleness engine's UpdateFn contract:
     (params, opt_state, batch, key) -> (delta, new_opt_state, metrics)."""
     def update_fn(params, opt_state, batch, key):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        delta, new_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope(MODEL):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope(OPTIMIZER):
+            delta, new_state = optimizer.update(grads, opt_state, params)
         return delta, new_state, {"loss": loss}
 
     return update_fn
@@ -218,8 +222,10 @@ def make_sgd_update_fn(loss_fn, optimizer: Optimizer):
 def make_stochastic_update_fn(loss_fn, optimizer: Optimizer):
     """Same, for losses that consume a PRNG key (VAE blackbox VI)."""
     def update_fn(params, opt_state, batch, key):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch, key)
-        delta, new_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope(MODEL):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch, key)
+        with jax.named_scope(OPTIMIZER):
+            delta, new_state = optimizer.update(grads, opt_state, params)
         return delta, new_state, {"loss": loss}
 
     return update_fn
