@@ -72,6 +72,11 @@ class StaleSyncConfig:
     # once per step with no per-step pack/unpack. Requires kernels=True and
     # an optimizer carrying an Adam spec (optimizers.adam().spec).
     fused_update: bool = False
+    # Derived from the placement (rules.worker_axis_split, which the
+    # engine's plan shards the ring by), not a knob: True where the ring's
+    # worker axis lies split over devices. It picks the per-worker read's
+    # form (``ring_read``); either form is correct on any placement.
+    worker_axis_split: bool = False
 
     def __post_init__(self):
         if self.delay is None:
@@ -87,6 +92,20 @@ class StaleSyncConfig:
     @property
     def slots(self) -> int:
         return max(self.s, 1)
+
+    @property
+    def ring_read(self) -> Tuple[str, str]:
+        """(form, why) of the per-worker ring read: ``"rows"`` reads each
+        worker's row by its own slice (``_ring_rows``), whose reads fuse
+        into their consumers; ``"gather"`` is one batched index
+        (``_ring_rows_gathered``) where nothing can fuse them: packed rows
+        feed a Pallas kernel (a stack of slices costs the TPU two passes
+        over them), or the worker axis lies split over devices."""
+        if self.kernels:
+            return "gather", "packed rows feed a kernel"
+        if self.worker_axis_split:
+            return "gather", "worker axis split over devices"
+        return "rows", "worker axis on one device"
 
 
 @jax.tree_util.register_dataclass
@@ -131,14 +150,44 @@ def init_state(params: Pytree, optimizer: Optimizer, cfg: StaleSyncConfig,
 
 
 def _ring_rows(ring: jax.Array, read: jax.Array) -> jax.Array:
-    """Worker p's row from slot ``read[p]`` of a [slots, P, ...] ring ->
-    [P, ...]. A dynamic index batched over the worker axis, not
-    ``take_along_axis``: the TPU compiler splits a plain gather whose slice
-    spans a packed row into pieces in proportion to its width, which took
-    longer than 15 minutes to compile at deepseek-7b widths."""
+    """Worker q's row from slot ``read[q]`` of a [slots, P, ...] ring ->
+    [P, ...], as P static per-worker dynamic slices (P is static).
+
+    Each row is one dynamic slice of the ring itself (not of a static
+    ``ring[:, q]``, which the TPU compiler copies whole, every slot of
+    it). Where the worker axis sits on one device, a consumer that takes
+    ``rows[q]`` (the worker mean, ``_worker_mean``) then reads the ring
+    in its own fusion, so no [P, ...] copy of the rows is written. The
+    batched form (``_ring_rows_gathered``) lowers to a gather, which the
+    TPU compiler expands into one loop per leaf writing that copy. On a
+    worker axis split over devices a slice of one worker would move whole
+    shards between devices, so there the gather stays
+    (``StaleSyncConfig.ring_read``)."""
+    rest = ring.shape[2:]
+    return jnp.stack([
+        jax.lax.dynamic_slice(ring, (read[q], q) + (0,) * len(rest),
+                              (1, 1) + rest).reshape(rest)
+        for q in range(ring.shape[1])])
+
+
+def _ring_rows_gathered(ring: jax.Array, read: jax.Array) -> jax.Array:
+    """``_ring_rows`` as one dynamic index batched over the worker axis,
+    where nothing fuses the row reads (``StaleSyncConfig.ring_read``).
+    Not ``take_along_axis``: the TPU
+    compiler splits a plain gather whose slice spans a packed row into
+    pieces in proportion to its width, which took longer than 15 minutes
+    to compile at deepseek-7b widths."""
     return jax.vmap(
         lambda col, r: jax.lax.dynamic_index_in_dim(col, r, 0, keepdims=False),
         in_axes=(1, 0))(ring, read)
+
+
+def _worker_mean(rows: jax.Array) -> jax.Array:
+    """fp32 mean of [P, ...] rows over the worker axis, summed row by row
+    so that each row is read where the sum is consumed; bitwise
+    ``rows.astype(float32).mean(axis=0)`` for P <= 2."""
+    p = rows.shape[0]
+    return sum(rows[q].astype(jnp.float32) for q in range(p)) / p
 
 
 def make_stale_train_step(
@@ -197,6 +246,16 @@ def make_stale_train_step(
         source = cfg.delay.realize(
             num_workers=p if cfg.per_worker_delays else None)
     clamp_slots = source.bound > cfg.slots - 1
+
+    read_form, read_why = cfg.ring_read
+
+    def ring_rows(ring, read):
+        """Each worker's delayed row of a [slots, P, ...] ring in the form
+        of ``cfg.ring_read``, recorded in the dispatch report."""
+        dispatch.note("ring_read", read_form, read_why)
+        if read_form == "rows":
+            return _ring_rows(ring, read)
+        return _ring_rows_gathered(ring, read)
 
     def per_worker_grads(params, batch):
         def one(b):
@@ -266,7 +325,7 @@ def make_stale_train_step(
                 acc, thr, mom_in = compensator.ef_inputs(
                     comp, pack_grads(gtree), spec.total)
                 if per:
-                    sel = _ring_rows(state.gbuf, read)
+                    sel = ring_rows(state.gbuf, read)
                     weights = jnp.full((p,), 1.0 / p, jnp.float32)
                 else:
                     sel = jax.lax.dynamic_index_in_dim(state.gbuf, read, 0,
@@ -298,7 +357,7 @@ def make_stale_train_step(
                     state.gbuf, pack_grads(gtree, state.gbuf.dtype), write,
                     0)
                 if per:
-                    sel = _ring_rows(gbuf, read)
+                    sel = ring_rows(gbuf, read)
                     weights = jnp.full((p,), 1.0 / p, jnp.float32)
                 else:
                     sel = jax.lax.dynamic_index_in_dim(gbuf, read, 0,
@@ -403,11 +462,16 @@ def make_stale_train_step(
 
             if cfg.kernels:
                 # [P, D]: each worker's delayed packed row, fused-averaged.
-                sel = _ring_rows(gbuf, read)
+                sel = ring_rows(gbuf, read)
                 agg = kernel_agg(sel, jnp.full((p,), 1.0 / p, jnp.float32))
+            elif read_form == "rows":
+                # Summed row by row, so the row reads fuse into the
+                # optimizer's consumers of agg (_ring_rows).
+                agg = jax.tree.map(
+                    lambda buf: _worker_mean(ring_rows(buf, read)), gbuf)
             else:
                 agg = jax.tree.map(
-                    lambda buf: _ring_rows(buf, read).astype(
+                    lambda buf: ring_rows(buf, read).astype(
                         jnp.float32).mean(axis=0), gbuf)
             staleness = d
         else:

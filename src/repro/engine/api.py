@@ -44,6 +44,7 @@ from repro.core import ssp as ssp_lib
 from repro.core import stale_sync, staleness
 from repro.delays.models import DelaySpec, UniformDelay
 from repro.optim import optimizers as optlib
+from repro.sharding import rules as rules_lib
 
 Pytree = Any
 
@@ -353,7 +354,6 @@ def kernel_placement_ok(kernels: str, arch=None, mesh=None) -> Tuple[bool, str]:
     if kernels == "off":
         return False, "config off"
     from repro.kernels import dispatch
-    from repro.sharding import rules as rules_lib
     arch_id = getattr(arch, "arch_id", arch)
     if arch_id in rules_lib.FSDP_ARCHS:
         return False, "FSDP placement"
@@ -596,6 +596,9 @@ def build_engine(api_or_loss, optimizer: Optional[optlib.Optimizer],
     if loss is None or optimizer is None:
         raise ValueError(f"{mode} mode needs (loss, optimizer)")
     mega = resolve_mega(kernel_delivery, why or "tree delivery")
+    # The per-worker ring read takes its form from the ring's placement, by
+    # the predicate the plan shards the ring with (StaleSyncConfig).
+    split = rules_lib.worker_axis_split(mesh, cfg.num_workers)
     if mode == "ssp":
         if cfg.delay is not None:
             # Trace/Schedule specs replace the sampled lognormal speed model
@@ -627,7 +630,8 @@ def build_engine(api_or_loss, optimizer: Optional[optlib.Optimizer],
         scfg = stale_sync.StaleSyncConfig(
             num_workers=cfg.num_workers, s=cfg.s + 1,
             buffer_dtype=cfg.buffer_dtype, delay_table=table,
-            kernels=kernel_delivery, fused_update=mega)
+            kernels=kernel_delivery, fused_update=mega,
+            worker_axis_split=split)
         meta["ssp_schedule"] = table
         max_bound = cfg.s
     else:
@@ -652,7 +656,8 @@ def build_engine(api_or_loss, optimizer: Optional[optlib.Optimizer],
             delay_table=table,
             buffer_dtype=cfg.buffer_dtype,
             per_worker_delays=cfg.per_worker_delays,
-            kernels=kernel_delivery, fused_update=mega)
+            kernels=kernel_delivery, fused_update=mega,
+            worker_axis_split=split)
         eff_bound = spec.bound if spec is not None else scfg.delay.bound
         if eff_bound > scfg.slots - 1:
             # A delay the ring can't hold would silently wrap onto a much
@@ -662,6 +667,8 @@ def build_engine(api_or_loss, optimizer: Optional[optlib.Optimizer],
                 f"({scfg.slots} slots from s={cfg.s}); raise s to at least "
                 f"{eff_bound + 1}")
         max_bound = eff_bound
+    if scfg.per_worker_delays:
+        meta["kernels"]["ring_read"] = scfg.ring_read[0]
     raw = stale_sync.make_stale_train_step(loss, optimizer, scfg,
                                            compensator=compensator)
 

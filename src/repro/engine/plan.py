@@ -187,7 +187,8 @@ def attach_train_plan(engine: Engine, api: ModelAPI, shape: ShapeLike, *,
     fsdp = arch_id in rules_lib.FSDP_ARCHS
     rules = rules or rules_lib.rules_for_arch(arch_id, shape=shape, mesh=mesh)
     wax = rules_lib.worker_axes(mesh)
-    if wax is not None and p % rules_lib.data_extent(mesh):
+    if (rules_lib.data_extent(mesh) > 1
+            and not rules_lib.worker_axis_split(mesh, p)):
         wax = None  # jit args must divide evenly; replicate the worker axis
 
     params_shapes, params_axes = captured_axes(api.init)

@@ -65,6 +65,17 @@ def worker_axes(mesh: Mesh):
     return kept if len(kept) > 1 else (kept[0] if kept else None)
 
 
+def worker_axis_split(mesh: Optional[Mesh], p: int) -> bool:
+    """Does a leading worker axis of extent ``p`` lie split over several
+    devices? The planner shards it over ``worker_axes(mesh)`` only where
+    the data extent divides ``p`` evenly (jit arguments must), and a data
+    extent of 1 splits nothing. No mesh: one device."""
+    if mesh is None or worker_axes(mesh) is None:
+        return False
+    extent = data_extent(mesh)
+    return extent > 1 and p % extent == 0
+
+
 def rules_for_arch(arch_id: Optional[str], shape=None, mesh: Optional[Mesh] = None,
                    extra: Optional[dict] = None) -> dict:
     """The rule set the sharding planner uses for one (arch, shape, mesh):
