@@ -68,7 +68,7 @@ def train_memory(cell, devices, engine, seed, emit):
     row = {"seed": seed, "kind": "memory",
            "start": memory(devices, "peak_bytes_in_use")}
     ks = program.key_seed(seed)
-    params = weights.make(cell.config, jax.random.PRNGKey(ks))
+    params = weights.make(cell.model, cell.config, jax.random.PRNGKey(ks))
     jax.block_until_ready(params)
     row["weights"] = memory(devices, "peak_bytes_in_use")
     state = engine.init(jax.random.PRNGKey(ks), params=params)

@@ -10,7 +10,8 @@ steps). ``train_tokens_per_s`` is every worker's tokens in the steps the
 window completed, over the window's wall time, device work included.
 
 Correctness, once the window has closed and the program's state is freed:
-the plain float32 reference (``bench/reference.py``) follows the same first
+the plain float32 reference (``bench/reference.py`` with the family's
+forward, ``bench/models/<model>.py``) follows the same first
 steps from the same seed (enough of them that the ring's slots are written
 twice and read back), and these numbers are held to their limits:
 
@@ -49,7 +50,7 @@ def build(cell, devices):
     from repro.launch import mesh as meshlib
     from repro.launch.train import build_train_engine
     cfg, tr = cell.config, cell.traffic
-    arch, api = program.model_api(cfg)
+    arch, api = program.model_api(cell.model, cfg)
     mesh = meshlib.parse_host_mesh(tr["mesh"])
     if mesh.devices.size != len(devices):
         raise ValueError(f"mesh {tr['mesh']} spans {mesh.devices.size} "
@@ -68,8 +69,8 @@ class FirstSteps(Hook):
     after step 1, and the parameters' change after the last checked step
     (against the seed's weights made again)."""
 
-    def __init__(self, cfg: dict, key_seed: int, steps: int):
-        self.cfg, self.key_seed, self.steps = cfg, key_seed, steps
+    def __init__(self, cell, key_seed: int, steps: int):
+        self.cell, self.key_seed, self.steps = cell, key_seed, steps
         self.losses, self.first_grad, self.change = [], None, None
 
     def on_step(self, ctx):
@@ -79,14 +80,14 @@ class FirstSteps(Hook):
         self.losses.append(ctx.metrics["loss"])
         inner = ctx.state.inner
         if ctx.step == 0:
-            b1 = self.cfg["optimizer"]["b1"]
+            b1 = self.cell.config["optimizer"]["b1"]
             m = inner.opt_state["m"]
             self.first_grad = reference.leaf_norms(m) / (1.0 - b1)
             self.first_tree = jax.tree.map(
                 lambda x: np.asarray(x, np.float32) / (1.0 - b1),
                 jax.device_get(m))
         if ctx.step == self.steps - 1:
-            params0 = weights.make(self.cfg,
+            params0 = weights.make(self.cell.model, self.cell.config,
                                    jax.random.PRNGKey(self.key_seed))
             self.change = reference.leaf_change_norms(inner.params, params0)
             del params0
@@ -127,10 +128,11 @@ def first_steps(engine, cell, seed: int):
     ks = program.key_seed(seed)
     # The state takes (and the step donates) its own key arrays.
     state = engine.init(jax.random.PRNGKey(ks),
-                        params=weights.make(cfg, jax.random.PRNGKey(ks)))
+                        params=weights.make(cell.model, cfg,
+                                            jax.random.PRNGKey(ks)))
     feed = generator.markov_batches(seed, cfg["vocab_size"], tr["batch"],
                                     tr["seq"], tr["data"]["fan_out"])
-    rec = FirstSteps(cfg, ks, tr["check_steps"])
+    rec = FirstSteps(cell, ks, tr["check_steps"])
     result = Trainer(engine, hooks=[rec]).run(
         lambda: {"tokens": next(feed)}, tr["check_steps"], state=state,
         log_every=tr["log_every"])
@@ -149,10 +151,11 @@ def reference_readings(cell, seed: int, *, quant=None, fault: str = "none"):
     batches = [next(feed) for _ in range(tr["check_steps"])]
     delays = reference.uniform_delays(ks, tr["check_steps"], tr["workers"],
                                       tr["staleness"])
-    params0 = weights.make(cfg, jax.random.PRNGKey(ks))
+    params0 = weights.make(cell.model, cfg, jax.random.PRNGKey(ks))
     per = tr["batch"] // tr["workers"]
     out = reference.stale_psum(
-        params0, batches, delays, cfg, cfg["optimizer"], quant=quant,
+        cell.model, params0, batches, delays, cfg, cfg["optimizer"],
+        quant=quant,
         rows=slice(0, per // 2) if fault == "half_batch" else None,
         own_worker=0 if fault == "own_worker" else None)
     out["delays"] = delays.tolist()

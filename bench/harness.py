@@ -8,17 +8,32 @@ Everything that belongs to one cell is data found by name:
 
 * ``BENCHMARK.json`` (the checkout root): the cell's configuration, traffic
   mix and chip count, and the metrics it reports;
-* ``bench/configs/<config>.json``: the model as it is run, its source and cut;
+* ``bench/configs/<config>.json``: the model as it is run, its source and
+  cut, and its family under ``"model"``;
+* ``bench/models/<model>.py``: the family's module, one per family, which
+  owns everything that depends on it:
+  ``overrides(cfg) -> dict``, the program's config fields the file sets;
+  ``check(cfg, program_cfg)``, which raises where a width of the program
+  differs from the file; ``shapes(cfg)`` (the weights' tree of leaf
+  shapes) and ``fan_in(name, shape)``, asked of every leaf, the layout
+  ``bench/weights.py`` makes; ``logits(params, tokens, cfg, quant)``, the
+  plain float32 reference forward built on ``bench/reference.py``'s
+  ``mm``; ``train_step_flops(cfg, batch, seq)``, the model operations of
+  one training step (``bench/flops.py`` has the conventions);
 * ``bench/traffic/<traffic>.json``: the driver kind (``train``)
   and the parameters that kind's generator reads;
 * ``bench/limits/<cell>.json``: the limit of each number the correctness
   check compares;
 * ``bench/drivers/<kind>.py``: one driver per kind (``run(ctx) -> Outcome``);
 * ``bench/metrics/<metric>.py``: one reader per per-layer metric
-  (``read(run) -> float | None``).
+  (``read(run) -> float | None``); a layer the program names in its step
+  is read by ``bench/layers.py``'s ``layer_ms(run, <layer>)``.
 
 A later cell, configuration or per-layer metric is new files and new
-entries in ``BENCHMARK.json``; no code here changes.
+entries in ``BENCHMARK.json``; no code here changes. A configuration of
+another family is its config file, its ``bench/models/<model>.py``, its
+traffic and limits files, any reader files, and its cell's name appended
+to the ``workloads`` lists of the metrics it reports.
 """
 from __future__ import annotations
 
@@ -53,6 +68,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     bench: pathlib.Path
+    model: Any                          # the module bench/models/<model>.py
 
 
 @dataclasses.dataclass
@@ -100,13 +116,14 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
     limits_path = bench / "limits" / f"{name}.json"
+    config = _json(root / configs[w["config"]]["file"])
     return Cell(
         name=name, chips=int(w["chips"]), config_name=w["config"],
-        traffic_name=w["traffic"],
-        config=_json(root / configs[w["config"]]["file"]),
+        traffic_name=w["traffic"], config=config,
         traffic=_json(bench / "traffic" / f"{w['traffic']}.json"),
         limits=_json(limits_path) if limits_path.exists() else {},
-        end_to_end=e2e, per_layer=per_layer, bench=bench)
+        end_to_end=e2e, per_layer=per_layer, bench=bench,
+        model=load_model(bench, config))
 
 
 def _module(path: pathlib.Path):
@@ -117,6 +134,16 @@ def _module(path: pathlib.Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_model(bench: pathlib.Path, config: dict):
+    """The family module ``bench/models/<model>.py`` that ``config`` names
+    under ``"model"``."""
+    if "model" not in config:
+        raise ValueError(f"configuration {config.get('name')!r} names no "
+                         f'model family: give it "model": "<name>" for '
+                         f"bench/models/<name>.py")
+    return _module(bench / "models" / f"{config['model']}.py")
 
 
 def load_driver(cell: Cell):

@@ -1,10 +1,12 @@
 """The training step by layer, from a reduced trace (``bench/tracing.py``).
 
 The program names its layers twice. Inside the step program, named scopes
-(``repro.scopes``) put each instruction under ``forward``, ``backward``,
-``ring``, ``optimizer`` or ``other``; ``Engine.compiled_step_text`` gives the
-compiled program, whose instruction names are the names the device trace
-gives its operations. On the host, ``Trainer.run`` wraps each step in a
+(``repro.scopes``) put each instruction under a layer: today ``forward``,
+``backward``, ``ring``, ``optimizer`` or ``other``. Any other name the op
+map gives is counted under its own key, so a scope the program opens
+later, such as ``experts``, needs only a reader that calls ``layer_ms``.
+``Engine.compiled_step_text`` gives the compiled program, whose
+instruction names are the names the device trace gives its operations. On the host, ``Trainer.run`` wraps each step in a
 profiler step span ``train`` holding ``trainer.batch``, ``trainer.dispatch``,
 ``trainer.hooks``, ``trainer.log`` and ``trainer.eval``.
 
@@ -29,6 +31,8 @@ import numpy as np
 
 from bench import harness, tracing
 
+# Reported even where no operation ran under them; other names as the op
+# map gives them.
 LAYERS = ("forward", "backward", "ring", "optimizer", "other")
 STEP_SPAN, DISPATCH_SPAN = "train", "trainer.dispatch"
 IDLE_SPANS = {"sync": "trainer.log", "feed": "trainer.batch"}
@@ -42,17 +46,19 @@ def _is_module(name: str, module: str) -> bool:
 
 def layer_seconds(summary: tracing.Summary, op_layers: Dict[str, str],
                   module: str) -> Dict[str, float]:
-    """Device seconds by layer (``LAYERS``), averaged over the chips. Each
-    instant of an operation counts once, under the layer of the operation
-    that started first among those covering it (the outermost, for nested
-    operations); operations outside the ``module`` program's module events
-    are ``other``."""
+    """Device seconds by layer (``LAYERS`` and every other layer that
+    ``op_layers`` names), averaged over the chips. Each instant of an
+    operation counts once, under the layer of the operation that started
+    first among those covering it (the outermost, for nested operations);
+    operations outside the ``module`` program's module events are
+    ``other``."""
+    names = LAYERS + tuple(sorted(set(op_layers.values()) - set(LAYERS)))
     per = []
     for d in summary.devices:
         runs = tracing._merge([(s, e) for n, s, e in d.modules
                                if _is_module(n, module)])
         starts = [s for s, _ in runs]
-        tot = dict.fromkeys(LAYERS, 0)
+        tot = dict.fromkeys(names, 0)
         covered = None
         for name, s, e in sorted(d.ops, key=lambda o: (o[1], -o[2])):
             if covered is not None and e <= covered:
@@ -64,7 +70,7 @@ def layer_seconds(summary: tracing.Summary, op_layers: Dict[str, str],
             tot[op_layers.get(name, "other") if inside else "other"] += e - lo
         per.append(tot)
     return {k: float(np.mean([t[k] for t in per])) / 1e9 if per else 0.0
-            for k in LAYERS}
+            for k in names}
 
 
 def idle_in_span_seconds(summary: tracing.Summary, span: str) -> float:
@@ -143,8 +149,10 @@ def _readings(run) -> Optional[dict]:
 
 
 def layer_ms(run, layer: str) -> Optional[float]:
+    """Device ms per step under ``layer``; None where the trace has nothing
+    to read or the program names no such layer."""
     r = readings(run)
-    if r is None or "layers" not in r:
+    if r is None or layer not in r.get("layers", {}):
         return None
     return 1e3 * r["layers"][layer]
 

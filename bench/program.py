@@ -1,6 +1,7 @@
 """How the benchmark reaches the system under test: the program's model
-built from a configuration file, with every width checked against the file,
-and the helpers the drivers share (seeds, memory, the profiler window)."""
+built from a configuration file through its family's module, with every
+width checked against the file, and the helpers the drivers share (seeds,
+memory, the profiler window)."""
 from __future__ import annotations
 
 import contextlib
@@ -17,36 +18,27 @@ def key_seed(seed: int) -> int:
     return int(np.random.SeedSequence(int(seed)).generate_state(1)[0] >> 1)
 
 
-def overrides(cfg: dict) -> dict:
-    """The program's config fields set from the file: depth, vocabulary and
-    the norm's epsilon."""
-    return {"num_layers": cfg["num_hidden_layers"],
-            "vocab": cfg["vocab_size"], "vocab_real": cfg["vocab_size"],
-            "norm_eps": cfg["rms_norm_eps"]}
-
-
-def model_api(cfg: dict):
-    """(arch, api) of the program for configuration ``cfg``: the program's
-    published configuration (its small CPU variant where the file says
-    ``program_reduced``, for tests) with the fields of ``overrides`` set
-    from the file.
-    A width that differs between the program and the file is an error."""
+def model_api(model, cfg: dict):
+    """(arch, api) of the program for configuration ``cfg`` of the family
+    ``model`` (``bench/models/<model>.py``): the program's published
+    configuration (its small CPU variant where the file says
+    ``program_reduced``, for tests) with the fields of ``model.overrides``
+    set from the file. A width that differs between the program and the
+    file (``model.check``), or a type other than the file states, is an
+    error."""
     import jax.numpy as jnp
     from repro import configs as cfglib
     arch = cfglib.get(cfg["program_arch"])
     api = arch.api(reduced=bool(cfg.get("program_reduced", False)),
-                   overrides=overrides(cfg))
+                   overrides=model.overrides(cfg))
     c = api.cfg
-    have = {"hidden_size": c.d_model, "num_attention_heads": c.num_heads,
-            "num_key_value_heads": c.num_kv_heads, "head_dim": c.head_dim,
-            "intermediate_size": c.d_ff, "sliding_window": c.swa_window,
-            "rope_theta": c.rope_theta, "rms_norm_eps": c.norm_eps,
-            "compute_dtype": jnp.dtype(c.dtype).name,
+    have = {"compute_dtype": jnp.dtype(c.dtype).name,
             "param_dtype": jnp.dtype(c.param_dtype).name}
     wrong = {k: (v, cfg[k]) for k, v in have.items() if v != cfg[k]}
-    if wrong or c.moe is not None or c.qk_norm or c.logit_softcap:
+    if wrong:
         raise ValueError(f"program config differs from {cfg['name']}: "
                          f"(program, file) {wrong}")
+    model.check(cfg, c)
     return arch, api
 
 

@@ -1,17 +1,17 @@
-"""The plain reference: the configuration's transformer in float32.
+"""The plain reference: the configuration's model in float32, and the
+stale-psum training it follows.
 
-Straight ``jax.numpy`` from the published description, imported from
-nothing of the program: RMS norm, rotary positions (rotate-half layout,
-inverse frequencies theta^(-2i/hd)), causal attention with an optional
-sliding window, grouped K/V heads, a SiLU-gated MLP, a final norm and an
-untied output head; next-token cross-entropy. Every matmul runs at
-``Precision.HIGHEST``. Passing ``quant`` rounds both operands of every
-matmul to that dtype first, an 8-bit float tensor scaled so that its
-largest magnitude fills the type's range, as a low-precision matmul path
-would: the control of the correctness check computes the same mathematics
-one precision below the configuration's. The rounding passes gradients
-straight through, so the backward pass multiplies float32 cotangents by
-the rounded operands.
+Straight ``jax.numpy``, imported from nothing of the program. The forward
+pass is the family's own (``logits`` of ``bench/models/<model>.py``, found
+by the configuration's ``"model"``); this module holds what every family
+shares: the matmul, the RMS norm, next-token cross-entropy, the stale-psum
+delays and Adam. Every matmul runs at ``Precision.HIGHEST``. Passing
+``quant`` rounds both operands of every matmul to that dtype first, an
+8-bit float tensor scaled so that its largest magnitude fills the type's
+range, as a low-precision matmul path would: the control of the
+correctness check computes the same mathematics one precision below the
+configuration's. The rounding passes gradients straight through, so the
+backward pass multiplies float32 cotangents by the rounded operands.
 
 The stale-psum training reference follows the paper's delay model: worker p
 at step k applies its gradient from step k - d, d drawn uniformly from
@@ -22,6 +22,7 @@ mean.
 from __future__ import annotations
 
 import functools
+import json
 from typing import Optional
 
 import jax
@@ -43,80 +44,38 @@ def _q(x, quant):
     return x + jax.lax.stop_gradient(low - x)
 
 
-def _mm(spec, a, b, quant):
+def mm(spec, a, b, quant):
+    """``jnp.einsum`` at ``HIGHEST``, both operands rounded to ``quant``
+    first where it is given."""
     return jnp.einsum(spec, _q(a, quant), _q(b, quant), precision=HI)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
+    """RMS norm over the last axis."""
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos[:, None].astype(jnp.float32) * inv          # [S, half]
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def logits(params, tokens, cfg: dict, quant=None):
-    """tokens [B, S] -> logits [B, S, V], float32."""
-    b, s = tokens.shape
-    h, hkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    eps, theta, window = (cfg["rms_norm_eps"], cfg["rope_theta"],
-                          cfg.get("sliding_window"))
-    pos = jnp.arange(s)
-    allowed = pos[None, :] <= pos[:, None]
-    if window:
-        allowed &= pos[None, :] > pos[:, None] - window
-    x = params["embed"][tokens]
-    lay = params["layers"]
-    for i in range(cfg["num_hidden_layers"]):
-        a = _rms(x, lay["ln1"][i], eps)
-        q = _rope(_mm("bsd,dhk->bshk", a, lay["attn"]["wq"][i], quant),
-                  pos, theta)
-        k = _rope(_mm("bsd,dhk->bshk", a, lay["attn"]["wk"][i], quant),
-                  pos, theta)
-        v = _mm("bsd,dhk->bshk", a, lay["attn"]["wv"][i], quant)
-        k = jnp.repeat(k, h // hkv, axis=2)
-        v = jnp.repeat(v, h // hkv, axis=2)
-        sc = _mm("bqhk,bthk->bhqt", q, k, quant) / np.sqrt(hd)
-        sc = jnp.where(allowed[None, None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        o = _mm("bhqt,bthk->bqhk", p, v, quant)
-        x = x + _mm("bqhk,hkd->bqd", o, lay["attn"]["wo"][i], quant)
-        m = _rms(x, lay["ln2"][i], eps)
-        g = _mm("bsd,df->bsf", m, lay["mlp"]["w_gate"][i], quant)
-        u = _mm("bsd,df->bsf", m, lay["mlp"]["w_up"][i], quant)
-        x = x + _mm("bsf,fd->bsd", jax.nn.silu(g) * u,
-                    lay["mlp"]["w_down"][i], quant)
-    x = _rms(x, params["final_ln"], eps)
-    return _mm("bsd,dv->bsv", x, params["head"], quant)
-
-
-def loss(params, tokens, cfg: dict, quant=None):
-    """Mean next-token cross-entropy of tokens [B, S+1]."""
-    lg = logits(params, tokens[:, :-1], cfg, quant)
+def loss(model, params, tokens, cfg: dict, quant=None):
+    """Mean next-token cross-entropy of tokens [B, S+1] under the family
+    ``model``'s forward pass."""
+    lg = model.logits(params, tokens[:, :-1], cfg, quant)
     tgt = tokens[:, 1:]
     lse = jax.nn.logsumexp(lg, axis=-1)
     picked = jnp.take_along_axis(lg, tgt[..., None], -1)[..., 0]
     return jnp.mean(lse - picked)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "quant"))
-def _value_and_grad(params, tokens, cfg_items, quant):
-    return jax.value_and_grad(loss)(params, tokens, dict(cfg_items), quant)
+@functools.partial(jax.jit, static_argnames=("model", "cfg_text", "quant"))
+def _value_and_grad(params, tokens, model, cfg_text, quant):
+    return jax.value_and_grad(loss, argnums=1)(
+        model, params, tokens, json.loads(cfg_text), quant)
 
 
-def value_and_grad(params, tokens, cfg: dict, quant=None):
-    return _value_and_grad(params, tokens, _items(cfg), quant)
-
-
-def _items(cfg: dict):
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, str, type(None)))))
+def value_and_grad(model, params, tokens, cfg: dict, quant=None):
+    """(loss, gradient) of ``loss``. The whole configuration, nested groups
+    included, reaches the family's forward pass."""
+    return _value_and_grad(params, tokens, model,
+                           json.dumps(cfg, sort_keys=True), quant)
 
 
 def uniform_delays(key_seed: int, steps: int, workers: int, s: int):
@@ -157,10 +116,11 @@ def leaf_change_norms(tree, base) -> jax.Array:
         for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(base))])
 
 
-def stale_psum(params0, batches, delays, cfg: dict, opt: dict, *,
+def stale_psum(model, params0, batches, delays, cfg: dict, opt: dict, *,
                quant=None, rows: Optional[slice] = None,
                own_worker: Optional[int] = None) -> dict:
-    """Follow the first ``len(batches)`` stale-psum steps.
+    """Follow the first ``len(batches)`` stale-psum steps of the family
+    ``model``.
 
     ``batches[k]`` is step k's global batch [B, S+1]; worker p takes rows
     [p B/P, (p+1) B/P). Returns per-step mean losses, the leaf norms of the
@@ -192,8 +152,9 @@ def stale_psum(params0, batches, delays, cfg: dict, opt: dict, *,
                 shard = shard[rows]
             g, lv = None, 0.0
             for r in range(shard.shape[0]):
-                lr_, gr = value_and_grad(params, jnp.asarray(shard[r:r + 1]),
-                                         cfg, quant)
+                lr_, gr = value_and_grad(model, params,
+                                         jnp.asarray(shard[r:r + 1]), cfg,
+                                         quant)
                 lv += float(lr_) / shard.shape[0]
                 g = gr if g is None else _add(g, gr)
             step_losses.append(lv)

@@ -13,7 +13,8 @@ for p in (str(REPO), str(REPO / "src")):
         sys.path.insert(0, p)
 
 TINY = {
-    "name": "tiny", "program_arch": "deepseek-7b", "program_reduced": True,
+    "name": "tiny", "model": "dense", "program_arch": "deepseek-7b",
+    "program_reduced": True,
     "hidden_size": 128, "num_attention_heads": 4, "num_key_value_heads": 4,
     "head_dim": 32, "intermediate_size": 256, "num_hidden_layers": 2,
     "vocab_size": 512, "rms_norm_eps": 1e-06, "rope_theta": 10000.0,

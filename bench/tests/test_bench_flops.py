@@ -1,11 +1,12 @@
-"""bench/flops.py against counts worked out by hand for both
+"""The operation counts (``bench/flops.py`` and the dense family's
+``bench/models/dense.py``) against counts worked out by hand for both
 configurations, and the peaks table."""
 import json
 
 import pytest
 
-import benchtest_support as sup  # noqa: F401  (puts the repo on sys.path)
-from bench import flops
+import benchtest_support as sup
+from bench import flops, harness
 
 CONFIGS = sup.REPO / "bench" / "configs"
 
@@ -14,16 +15,19 @@ def load(name):
     return json.loads((CONFIGS / f"{name}.json").read_text())
 
 
+dense = harness.load_model(sup.REPO / "bench", {"model": "dense"})
+
+
 def test_deepseek_7b_1l_by_hand():
     cfg = load("deepseek-7b-1L")
     attn = 4 * 4096 * 4096              # wq, wk, wv, wo: 32 x 128 = 4096
     mlp = 3 * 4096 * 11008
     head = 4096 * 8192
-    assert flops.matmul_params(cfg) == attn + mlp + head == 235_929_600
+    assert dense.matmul_params(cfg) == attn + mlp + head == 235_929_600
     # 4 sequences of 128: 6 N T, plus causal attention 3 x 4 H hd pairs
     pairs = 128 * 129 // 2
     want = 6 * 235_929_600 * 512 + 3 * 4 * (4 * 32 * 128 * pairs)
-    assert flops.train_step_flops(cfg, 4, 128) == want
+    assert dense.train_step_flops(cfg, 4, 128) == want
     assert abs(want / 1e12 - 0.7264) < 1e-4
 
 
@@ -32,11 +36,11 @@ def test_danube_2l_by_hand():
     attn = 2 * 2560 * 32 * 80 + 2 * 2560 * 8 * 80     # wq, wo; wk, wv (GQA)
     mlp = 3 * 2560 * 6912
     head = 2560 * 32000
-    assert flops.matmul_params(cfg) == 2 * (attn + mlp) + head == 220_856_320
+    assert dense.matmul_params(cfg) == 2 * (attn + mlp) + head == 220_856_320
     # 2048 tokens stay inside the 4096 window: plain causal pairs
     pairs = 2048 * 2049 // 2
     want = 6 * 220_856_320 * 8192 + 3 * 4 * 2 * (4 * 32 * 80 * pairs)
-    assert flops.train_step_flops(cfg, 4, 2048) == want
+    assert dense.train_step_flops(cfg, 4, 2048) == want
     assert abs(want / 1e12 - 11.37) < 0.01
 
 
@@ -48,11 +52,11 @@ def test_window_caps_attended_pairs():
 
 def test_decode_and_paged_attention_counts():
     cfg = load("deepseek-7b-1L")
-    n = flops.matmul_params(cfg)
+    n = dense.matmul_params(cfg)
     # two slots attending 10 and 20 keys
-    assert flops.decode_step_flops(cfg, [10, 20]) == (
+    assert dense.decode_step_flops(cfg, [10, 20]) == (
         2 * n * 2 + 4 * 32 * 128 * 30)
-    f, b = flops.paged_attention_cost(cfg, [9, 19])
+    f, b = dense.paged_attention_cost(cfg, [9, 19])
     assert f == 4 * 32 * 128 * 30
     kv_row = 32 * 128 * 4                     # float32 pool, per K or V
     small = 2 * 32 * 128 * 2 + 2 * 32 * 128 * 2

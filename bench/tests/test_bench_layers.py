@@ -1,7 +1,8 @@
 """The training step by layer (``bench/layers.py``): nested operations
 counted once under the outer one's layer, layers that add up to busy time,
-device idle inside the Trainer's host spans, the six readers on a recorded
-CPU trace of the tiny cell, and the accepted metrics unchanged."""
+a layer name the program adds counted under its own key, device idle
+inside the Trainer's host spans, the six readers on a recorded CPU trace
+of the tiny cell, and the accepted metrics unchanged."""
 import warnings
 
 import jax
@@ -53,6 +54,23 @@ def test_layers_add_up_to_busy(chips):
     s.devices[-1].ops.append(("fusion.3", 15 * MS, 16 * MS))
     secs = layers.layer_seconds(s, OP_LAYERS, "jit__wrap")
     assert sum(secs.values()) == pytest.approx(s.busy_s)
+
+
+def test_a_layer_the_program_names_later_is_counted():
+    # the program's op map names an ``experts`` layer: it is reported under
+    # its own name, the five as before, and all still add up to busy
+    s = summary()
+    op_layers = dict(OP_LAYERS, **{"fusion.4": "experts"})
+    secs = layers.layer_seconds(s, op_layers, "jit__wrap")
+    assert secs == {"forward": 0.0, "backward": pytest.approx(0.006),
+                    "optimizer": 0.0, "ring": pytest.approx(0.001),
+                    "other": pytest.approx(0.002),
+                    "experts": pytest.approx(0.002)}
+    assert sum(secs.values()) == pytest.approx(s.busy_s)
+    run = _run(None, s, 1)
+    run.outcome.layer_readings = {"steps": 1, "idle": {}, "layers": secs}
+    assert layers.layer_ms(run, "experts") == pytest.approx(2.0)
+    assert layers.layer_ms(run, "router") is None
 
 
 def test_idle_inside_a_named_span():

@@ -78,12 +78,13 @@ def test_program_runs_the_file_as_stated(name):
     from bench import program
     cell_cfg = json.loads((sup.REPO / "bench" / "configs" /
                            f"{name}.json").read_text())
-    _, api = program.model_api(cell_cfg)
+    model = harness.load_model(sup.REPO / "bench", cell_cfg)
+    _, api = program.model_api(model, cell_cfg)
     assert api.cfg.norm_eps == cell_cfg["rms_norm_eps"]
     assert api.cfg.num_layers == cell_cfg["num_hidden_layers"]
     wrong = dict(cell_cfg, hidden_size=cell_cfg["hidden_size"] + 1)
     with pytest.raises(ValueError):
-        program.model_api(wrong)
+        program.model_api(model, wrong)
 
 
 @pytest.mark.parametrize("name", _committed("workloads"))
